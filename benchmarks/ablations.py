@@ -20,6 +20,7 @@ import os
 from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core.federation import run_federation
 from repro.data import make_image_dataset, partition, train_test_split
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def setting(n_nodes=4, n=2400, split="iid", seed=0):
@@ -42,6 +43,7 @@ ABLATIONS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--split", default="iid")

@@ -60,6 +60,7 @@ import sys
 import tempfile
 
 from round_step import measure, measure_phases
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def check_wire(baseline_path: str, threshold: float) -> bool:
@@ -98,6 +99,9 @@ def check_wire(baseline_path: str, threshold: float) -> bool:
         out = tf.name
     script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "round_step.py")
+    # the child measures bytes on virtual host devices: it must never
+    # reach for the accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     try:
         r = subprocess.run(
             [sys.executable, script, "--wire",
@@ -105,7 +109,7 @@ def check_wire(baseline_path: str, threshold: float) -> bool:
              "--wire-topology", cfg["topology"],
              "--wire-bits", *bits_list, *adapter_args,
              "--pods", *pods_args, "--out", out],
-            capture_output=True, text=True)
+            capture_output=True, text=True, env=env)
         if r.returncode != 0:
             print(f"wire bench failed to run:\n{r.stdout}\n{r.stderr}")
             return True
@@ -270,6 +274,7 @@ def check_phases(baseline: dict, threshold: float, rounds: int) -> bool:
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--baseline", default="BENCH_round_step.json")
     ap.add_argument("--wire-baseline", default="BENCH_wire_exchange.json")

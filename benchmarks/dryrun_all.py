@@ -38,8 +38,7 @@ def run_one(arch: str, shape: str, mesh: str, force: bool) -> dict:
         if rep.get("status") == "ok":
             return rep
     t0 = time.time()
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", mesh, "--json", path],
@@ -82,8 +81,7 @@ def run_topo(arch: str, topology: str, pods: str, extra, tag: str,
         if rep.get("status") == "ok":
             return rep
     t0 = time.time()
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--topology", topology, "--pods", pods] + list(extra),

@@ -32,6 +32,7 @@ from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core.federation import run_federation
 from repro.data import make_image_dataset, partition, train_test_split
 from repro.wirespec import WireSpec
+from repro.launch.compile_cache import enable_compile_cache
 
 ALGOS = ["fedavg", "fedproto", "fml", "fedgpd", "profe"]
 
@@ -143,6 +144,7 @@ def run(dataset: str, split: str, *, nodes: int, rounds: int, epochs: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper protocol (20 nodes, 10+ rounds)")

@@ -45,9 +45,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import statistics
-import sys
 import time
 
 import jax
@@ -69,6 +67,7 @@ from repro.optim import (clip_by_global_norm, make_optimizer,
 from repro.optim.plane import (as_tree, is_plane, plane_from_tree,
                                plane_to_tree, plane_view_tree)
 from repro.wirespec import WireSpec, resolve_bits
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _block(tree):
@@ -218,9 +217,9 @@ def measure(n_nodes: int, *, samples_per_node: int, batch_size: int,
             fed.local_epochs)
         pxb, pvalid = F._stack_round_batches(
             node_data, batch_size, [fed.seed + rnd] * n_nodes, 1)
-        out = round_fn(stacked, xb, valid, pxb, pvalid, w_self, w_neigh,
-                       include, teacher_on=True,
-                       all_valid=bool(np.all(np.asarray(valid) == 1.0)))
+        out, _loss = round_fn(
+            stacked, xb, valid, pxb, pvalid, w_self, w_neigh, include,
+            teacher_on=True, all_valid=bool(np.all(np.asarray(valid) == 1.0)))
         _block(out)
         return out
 
@@ -289,8 +288,8 @@ def measure_phases(n_nodes: int, *, samples_per_node: int, batch_size: int,
 
         def round_fn(state, xb, valid, pxb, pvalid, teacher_on,
                      all_valid=False):
-            state, protos, counts = tr(state, xb, valid, pxb, pvalid,
-                                       teacher_on, all_valid)
+            state, protos, counts, _loss = tr(state, xb, valid, pxb,
+                                              pvalid, teacher_on, all_valid)
             state, rs, prx = sh(state, protos)
             return mx(state, rs, prx, counts, w_self, w_neigh, include)
 
@@ -679,18 +678,8 @@ def run_wire(args):
     print(f"wrote {args.out}")
 
 
-def _reexec_with_devices(n: int):
-    from repro.launch.wire import ensure_host_device_flag
-    env = ensure_host_device_flag(n, dict(os.environ))
-    if env.get("XLA_FLAGS") == os.environ.get("XLA_FLAGS"):
-        raise RuntimeError(
-            f"need {n} host devices but XLA_FLAGS pins a smaller count")
-    os.execve(sys.executable,
-              [sys.executable, os.path.abspath(__file__)] + sys.argv[1:],
-              env)
-
-
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", nargs="+", type=int, default=[2, 4, 8])
     ap.add_argument("--samples-per-node", type=int, default=32)
@@ -726,14 +715,16 @@ def main():
     args = ap.parse_args()
 
     if args.wire:
-        from repro.launch.wire import parse_pods
+        from repro.launch.wire import ensure_host_device_flag, parse_pods
         if args.pods is None:
             args.pods = [str(args.wire_nodes)]
         need = max(n * c for n, c in map(parse_pods, args.pods))
         if args.out == "BENCH_round_step.json":
             args.out = "BENCH_wire_exchange.json"
-        if jax.device_count() < need:
-            _reexec_with_devices(need)
+        # the wire sweep measures bytes on virtual host devices: pin the
+        # CPU and the device count before the first jax call
+        jax.config.update("jax_platforms", "cpu")
+        ensure_host_device_flag(need)
         args.rounds = max(args.rounds, 10)
         run_wire(args)
         return

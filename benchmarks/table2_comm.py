@@ -26,6 +26,7 @@ import numpy as np
 from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core.federation import run_federation
 from repro.data import make_image_dataset, partition, train_test_split
+from repro.launch.compile_cache import enable_compile_cache
 
 ALGOS = ["fedavg", "fedgpd", "fml", "fedproto", "profe"]
 PAPER_ROUNDS = {"mnist-cnn": 10, "cifar10-resnet18": 20,
@@ -96,6 +97,7 @@ def logical_wire(dataset: str, nodes: int, topology: str, bits="16",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--datasets", nargs="+", default=["mnist-cnn"])

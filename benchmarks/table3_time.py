@@ -28,6 +28,7 @@ import statistics
 from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core.federation import run_federation, run_federation_loop
 from repro.data import make_image_dataset, partition, train_test_split
+from repro.launch.compile_cache import enable_compile_cache
 
 ALGOS = ["fedavg", "fedgpd", "fml", "fedproto", "profe"]
 
@@ -145,6 +146,7 @@ def measure_floor(dataset: str, *, nodes: int, rounds: int, n_samples: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="the paper's N=20 protocol on the stacked engine")

@@ -10,9 +10,11 @@ from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core.federation import run_federation
 from repro.data import make_image_dataset, partition, train_test_split
 from repro.models import derive_student
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--nodes", type=int, default=3)

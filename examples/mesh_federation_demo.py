@@ -6,23 +6,28 @@ simulated devices, and prints the collective schedule the 512-chip
 dry-run sees.
 
     PYTHONPATH=src python examples/mesh_federation_demo.py
-"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
+The demo runs on the CPU whatever the host holds: ``main`` pins the
+CPU platform and 8 host devices before the first jax call.
+"""
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.config import get_config
 from repro.core.mesh_federation import make_fedavg_round, make_profe_round
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_mesh
+from repro.launch.wire import ensure_host_device_flag
 from repro.models import derive_student, init_params
 from repro.sharding import param_specs
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    jax.config.update("jax_platforms", "cpu")
+    ensure_host_device_flag(8)
+    enable_compile_cache()
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     print(f"mesh: {dict(mesh.shape)} = {mesh.devices.size} devices")
 
     cfg = get_config("yi-6b").smoke()

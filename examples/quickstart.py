@@ -9,9 +9,11 @@ communication saving — the paper's two headline numbers.
 from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core.federation import run_federation
 from repro.data import make_image_dataset, partition, train_test_split
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("mnist-cnn")
     print(f"teacher: {cfg.name}  channels={cfg.cnn_channels}")
 

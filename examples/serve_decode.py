@@ -13,9 +13,11 @@ import jax.numpy as jnp
 from repro.config import get_config
 from repro.models import decode_step, init_cache, init_params, prefill
 from repro.models.model import build_memory
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--batch", type=int, default=4)

@@ -15,20 +15,22 @@ ring the ppermute exchange moves O(degree), not O(N), per node.
 """
 import argparse
 
-from repro.launch.wire import ensure_host_device_flag
-
-_N_DEFAULT = 4
-# one host device per federation node for the physical-bytes lowering
-# (must precede the first jax use; --nodes above 8 needs a manual flag)
-ensure_host_device_flag(8)
-
 from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core import topology as T
 from repro.core.federation import run_federation
 from repro.data import make_image_dataset, partition, train_test_split
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.wire import ensure_host_device_flag
+
+_N_DEFAULT = 4
 
 
 def main():
+    # one host device per federation node for the physical-bytes
+    # lowering (before the first jax use; --nodes above 8 needs a
+    # manual flag)
+    ensure_host_device_flag(8)
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--nodes", type=int, default=_N_DEFAULT)

@@ -408,6 +408,12 @@ def _make_proto_pass(proto_cfg: ModelConfig, ncls: int):
     return proto_pass
 
 
+def _mean_loss(losses, valid):
+    """Mean of the per-step, per-node losses ``[T, N]`` over the valid
+    steps (the padded steps of nodes with fewer batches drop out)."""
+    return jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0)
+
+
 def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
                       share_protos: bool, wire_model: Optional[str],
                       bits: Optional[int] | WireSpec,
@@ -418,7 +424,8 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
 
     * ``train_phase`` — local epochs (scan over the batch axis, vmap
       over nodes) + Eq. 3 prototype accumulation → ``(state, protos,
-      counts)``,
+      counts, loss)``, ``loss`` the student loss averaged over the
+      round's valid steps and nodes,
     * ``share_phase`` — the wire codec round-trip of this state's
       payload (what every receiver reconstructs; updates the
       error-feedback ``CodecState`` in place) → ``(state, recv_student,
@@ -494,26 +501,29 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
                 sums = sums + s_add * v[:, None, None]
                 counts = counts + c_add * v[:, None]
                 st = new if all_valid else _masked_select(v, new, st)
-                return (st, sums, counts), ()
+                return (st, sums, counts), m["loss_s"]
 
-            (state, sums, counts), _ = _scan(
+            (state, sums, counts), losses = _scan(
                 fbody, (state, sums0, counts0), (xb, valid),
                 valid.shape[0])
             state = state._replace(round_idx=state.round_idx + 1)
             if proto_ema and proto_ema > 0:
                 state = state._replace(proto_acc=(sums, counts))
-            return state, normalize_protos(sums, counts), counts
+            return (state, normalize_protos(sums, counts), counts,
+                    _mean_loss(losses, valid))
 
         def body(carry, inp):
             batch, v = inp
-            new, _ = jax.vmap(lambda s, b: step(s, b, teacher_on))(carry,
+            new, m = jax.vmap(lambda s, b: step(s, b, teacher_on))(carry,
                                                                    batch)
-            return (new if all_valid else _masked_select(v, new, carry)), ()
+            return (new if all_valid else _masked_select(v, new, carry)), \
+                m["loss_s"]
 
-        state, _ = _scan(body, state, (xb, valid), valid.shape[0])
+        state, losses = _scan(body, state, (xb, valid), valid.shape[0])
         state = state._replace(round_idx=state.round_idx + 1)
+        loss = _mean_loss(losses, valid)
         if not share_protos:
-            return state, (), ()
+            return state, (), (), loss
 
         # 2) Eq. 3 prototype accumulation: the factored exact pass
         #    (post-training student forward over the proto stream)
@@ -522,7 +532,7 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
             sums = sums + proto_ema * state.proto_acc[0]
             counts = counts + proto_ema * state.proto_acc[1]
             state = state._replace(proto_acc=(sums, counts))
-        return state, normalize_protos(sums, counts), counts
+        return state, normalize_protos(sums, counts), counts, loss
 
     def share_phase(state: NodeState, protos):
         # 3a) the wire: receiver-side reconstruction.  A node's own
@@ -611,7 +621,8 @@ def _make_round_fn(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
     """One full federation round as a single compiled program over
     stacked node state: scan(vmap(step)) → Eq. 3 proto pass (exact
     second stream, or fused into the training scan — ``proto_pass``) →
-    round_ops gossip/aggregate.  ``teacher_on`` is a static arg (two
+    round_ops gossip/aggregate → ``(state, loss)``, ``loss`` the
+    round's mean student loss.  ``teacher_on`` is a static arg (two
     program variants, exactly like the per-node step).
 
     The gossip/include matrices ``(w_self [N], w_neigh [N, N],
@@ -626,12 +637,12 @@ def _make_round_fn(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
 
     def round_fn(state: NodeState, xb, valid, pxb, pvalid,
                  w_self, w_neigh, include,
-                 teacher_on: bool, all_valid: bool = False) -> NodeState:
-        state, protos, counts = train_phase(state, xb, valid, pxb, pvalid,
-                                            teacher_on, all_valid)
+                 teacher_on: bool, all_valid: bool = False):
+        state, protos, counts, loss = train_phase(
+            state, xb, valid, pxb, pvalid, teacher_on, all_valid)
         state, recv_student, protos_rx = share_phase(state, protos)
         return mix_phase(state, recv_student, protos_rx, counts,
-                         w_self, w_neigh, include)
+                         w_self, w_neigh, include), loss
 
     donate = (0,) if jax.default_backend() != "cpu" else ()
     return jax.jit(round_fn, static_argnames=("teacher_on", "all_valid"),
@@ -797,14 +808,18 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
       ``t``'s device programs are in flight (JAX async dispatch).
     * ``"rounds"`` — stale-by-one mixing: round ``t`` mixes the payload
       *shared at round ``t-1``* (``state_t^+ = mix(state_t^-,
-      payload_{t-1})``; round 0 trains and shares but skips the mix),
-      so round ``t``'s share runs concurrently with round ``t+1``'s
-      local epochs — the round's critical path moves from ``train +
-      gossip`` toward ``max(train, gossip)``.  With error feedback the
+      payload_{t-1})``), so round ``t``'s share runs concurrently with
+      round ``t+1``'s local epochs — the round's critical path moves
+      from ``train + gossip`` toward ``max(train, gossip)``.  Round 0
+      is a sequential round (share and mix in the same round): the
+      nodes start from independent initializations, and mixing their
+      unaligned models stale-by-one dragged F1 down for several rounds.
+      Round 1 then trains and shares without a mix, and round ``t >=
+      2`` mixes payload ``t-1``.  With error feedback the
       ``CodecState.seq`` counter pins the pairing: the residual carried
       into share ``t`` is the one produced by share ``t-1`` (asserted
-      across carried rounds in tests).  A run of R rounds applies R-1
-      mixes; the final round's payload is shared but never consumed.
+      across carried rounds in tests).  A run of R >= 2 rounds applies
+      R-1 mixes; the final round's payload is shared but never consumed.
     """
     if overlap not in OVERLAPS:
         raise ValueError(f"overlap must be one of {OVERLAPS}, "
@@ -936,6 +951,9 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
         n_nodes)
     round_times: List[float] = []
     result.extras["round_times_s"] = round_times
+    losses: List[float] = []
+    result.extras["loss_per_round"] = losses
+    result.extras["engine"] = "stacked"
     t0 = time.time()
 
     empty = ({}, jnp.zeros((0, n_nodes), jnp.float32))
@@ -958,15 +976,18 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
             xb, valid = staged_next
             pxb, pvalid = proto_next
             p = sched.phase_index(rnd)
-            stacked, protos, counts = train_jit(
+            stacked, protos, counts, loss = train_jit(
                 stacked, xb, valid, pxb, pvalid, teacher_on=t_on,
                 all_valid=bool(np.all(np.asarray(valid) == 1.0)))
-            if overlap == "rounds":
+            if overlap == "rounds" and rnd > 0:
                 # stale-by-one: mix the payload shared LAST round into
                 # this round's trained state, then share this round's
                 # payload — its consumption waits until round t+1, so
                 # the device runs it concurrently with whatever the
-                # host (and the next round's training) does meanwhile
+                # host (and the next round's training) does meanwhile.
+                # Round 0 mixes synchronously (the else branch): nodes
+                # start from independent initializations, and a stale
+                # mix of unaligned models collapses the first rounds
                 if recv_prev is not None:
                     stacked = mix_jit(stacked, *recv_prev, w_self_st[p],
                                       w_neigh_st[p], include_st[p])
@@ -1003,6 +1024,7 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
                                   stacked_students=students)
             result.f1_per_round.append(f1)
             result.acc_per_round.append(acc)
+            losses.append(float(loss))
             round_times.append(time.time() - t_r)
             if verbose:
                 print(f"[{algo}/overlap={overlap}] round "
@@ -1028,10 +1050,10 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
         pxb, pvalid = proto_staged
 
         p = sched.phase_index(rnd)
-        stacked = round_fn(stacked, xb, valid, pxb, pvalid,
-                           w_self_st[p], w_neigh_st[p], include_st[p],
-                           teacher_on=t_on,
-                           all_valid=bool(np.all(np.asarray(valid) == 1.0)))
+        stacked, loss = round_fn(
+            stacked, xb, valid, pxb, pvalid, w_self_st[p], w_neigh_st[p],
+            include_st[p], teacher_on=t_on,
+            all_valid=bool(np.all(np.asarray(valid) == 1.0)))
 
         # metering is analytic and vectorized — per-copy bytes from the
         # payload skeleton times the schedule's degree vectors,
@@ -1046,6 +1068,7 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
                               stacked_students=students)
         result.f1_per_round.append(f1)
         result.acc_per_round.append(acc)
+        losses.append(float(loss))
         round_times.append(time.time() - t_r)
         if verbose:
             print(f"[{algo}] round {rnd + 1}/{fed.rounds} "
@@ -1184,6 +1207,7 @@ def run_federation_loop(teacher_cfg: ModelConfig, fed: FederationConfig,
             ef_qdq = jax.jit(
                 lambda t, s: ef_quantize_dequantize_tree(t, bits, s))
     result = FederationResult(comm=meter, algorithm=algo)
+    result.extras["engine"] = "loop"
     result.extras["proto_pass"] = fed.proto_pass
     result.extras["param_plane"] = use_plane
     if fed.proto_ema:

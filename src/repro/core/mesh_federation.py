@@ -62,8 +62,9 @@ payloads meet the same mix weights, and the collectives are
 byte-identical.  Round-level overlap (running round ``t``'s gossip
 concurrently with round ``t+1``'s local epochs, stale-by-one mixing)
 lives in the engine — ``core/federation.py run_federation(overlap=
-"rounds")``: round ``t`` mixes the payload quantized at round ``t-1``,
-round 0 skips the mix, and with error feedback the
+"rounds")``: round ``t >= 2`` mixes the payload quantized at round
+``t-1``, round 0 is sequential and round 1 skips the mix, and with
+error feedback the
 ``CodecState.seq`` counter pins which payload a carried residual
 corrects (the residual entering quantize ``t`` is the one produced by
 quantize ``t-1``, asserted in tests).
@@ -88,7 +89,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import topology as T
@@ -520,7 +521,7 @@ def _packed_round_core(mesh, student_specs, wire: WireSpec, adj):
             enc = shard_map(
                 lambda c: Q.encode_wire(c, seg_ids, seg_bits=seg_bits),
                 mesh=mesh, in_specs=(P("pod", None, None),),
-                out_specs=P("pod", None), check_rep=False)
+                out_specs=P("pod", None), check_vma=False)
             wire_buf = _constrain_buf(mesh, enc(codes), None)
             codes = Q.decode_wire(wire_buf, seg_ids, seg_bits=seg_bits)
             codes = jax.lax.with_sharding_constraint(
@@ -602,7 +603,7 @@ def _make_profe_round_ppermute(mesh, student_specs, wire: WireSpec,
                            P("pod"), P("pod", None)),
                  out_specs=(P("pod", None, None), P("pod", None, None),
                             P("pod", None)),
-                 check_rep=False)
+                 check_vma=False)
         def exchange(own_buf, codes, scales, counts, w_self, w_row):
             me = jax.lax.axis_index("pod")
             # serialize to the wire byte layout per device (inside the
@@ -778,7 +779,7 @@ def _make_profe_round_ppermute_sharded(mesh, student_specs, wire: WireSpec,
                            P("pod"), P("pod", None)),
                  out_specs=(P("pod", inner, None), P("pod", None, None),
                             P("pod", None)),
-                 check_rep=False)
+                 check_vma=False)
         def exchange(own_buf, codes_l, scales_l, counts_l, w_self, w_row):
             me = jax.lax.axis_index("pod")
             k = jnp.int32(0)                   # flattened inner index
@@ -1075,7 +1076,7 @@ def _make_profe_round_adapter(mesh, student_specs, wire: WireSpec,
             enc = shard_map(
                 lambda c: Q.encode_wire(c, seg_ids, seg_bits=seg_bits),
                 mesh=mesh, in_specs=(P("pod", None, None),),
-                out_specs=P("pod", None), check_rep=False)
+                out_specs=P("pod", None), check_vma=False)
             wire_buf = _constrain_buf(mesh, enc(codes), None)
             codes = Q.decode_wire(wire_buf, seg_ids, seg_bits=seg_bits)
             codes = jax.lax.with_sharding_constraint(
@@ -1116,7 +1117,7 @@ def _make_profe_round_adapter(mesh, student_specs, wire: WireSpec,
                            P("pod", None)),
                  out_specs=(P("pod", None, None, None),
                             P("pod", None, None)),
-                 check_rep=False)
+                 check_vma=False)
         def exchange(codes, scales, counts):
             # the physical wire: degree-many permutes of the encoded
             # spec-byte factor buffer (+ scales and counts).  Each
@@ -1216,7 +1217,7 @@ def _make_profe_round_adapter(mesh, student_specs, wire: WireSpec,
         regmean_local = partial(
             shard_map, mesh=mesh,
             in_specs=(P("pod"), P("pod"), P("pod")),
-            out_specs=P("pod"), check_rep=False)(
+            out_specs=P("pod"), check_vma=False)(
                 lambda a_, g_, c_: regmean_adjust(a_, g_, c_,
                                                   per_recv=True))
         for nm in layout.mat_names:
@@ -1314,7 +1315,7 @@ def make_fedavg_round(mesh, model_specs,
             @partial(shard_map, mesh=mesh,
                      in_specs=(P("pod", None, None), P("pod"),
                                P("pod", None)),
-                     out_specs=P("pod", None, None), check_rep=False)
+                     out_specs=P("pod", None, None), check_vma=False)
             def exchange_fp32(own_buf, w_self, w_row):
                 me = jax.lax.axis_index("pod")
                 recv, ws = [], []

@@ -95,7 +95,7 @@ def kd_loss_rows_pallas(student_logits, teacher_logits, temperature: float,
             pltpu.VMEM((br, 1), jnp.float32),   # m_s
             pltpu.VMEM((br, 1), jnp.float32),   # l_s
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(student_logits, teacher_logits)

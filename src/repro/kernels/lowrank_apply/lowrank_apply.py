@@ -19,7 +19,8 @@ Grid ``(N, ⌈d/bd⌉, ⌈k/bk⌉)``; the full sender bank ``B [S, bd, r]``
 rides VMEM per tile (S ≤ a few dozen nodes, r ~ 8 → KBs).  The
 RegMean variant indexes the per-receiver adjusted factors
 ``Ã [N, S, r, bk]`` by the grid's receiver coordinate.  ``coeffs``
-rows ride as a ``(1, S)`` block, scalar-read per sender.  Rank should
+``[N, S]`` rides SMEM whole (a ``(1, S)`` VMEM block would break the
+(8,128) tiling rule), scalar-read per receiver and sender.  Rank should
 be a multiple of 8 on real TPU hardware for fp32 tiling (the r = 8
 default is); interpret mode has no such constraint.
 """
@@ -30,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_D = 256
 BLOCK_K = 512
@@ -40,11 +42,13 @@ def _apply_kernel(n_send: int, per_recv: bool, w_ref, c_ref, b_ref, a_ref,
     # delta accumulates sender-sequentially, then ONE add onto w — the
     # same per-element order as ref.lowrank_delta_ref, so the plane
     # sweep's buffer-native add stays bit-identical to the kernel
+    i = pl.program_id(0)
     acc = None
     for j in range(n_send):
         aj = a_ref[0, j] if per_recv else a_ref[j]
-        term = c_ref[0, j] * jnp.dot(
+        term = c_ref[i, j] * jnp.dot(
             b_ref[j].astype(jnp.float32), aj.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         acc = term if acc is None else acc + term
     out_ref[0] = w_ref[0].astype(jnp.float32) + acc
@@ -65,7 +69,7 @@ def lowrank_apply_pallas(w, coeffs, b, a, *, interpret: bool = False):
         grid=(n, pl.cdiv(d, bd), pl.cdiv(k, bk)),
         in_specs=[
             pl.BlockSpec((1, bd, bk), lambda i, di, kj: (i, di, kj)),
-            pl.BlockSpec((1, s), lambda i, di, kj: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((s, bd, r), lambda i, di, kj: (0, di, 0)),
             a_spec,
         ],

@@ -19,6 +19,7 @@ the fused plane sweep exists to avoid.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -39,10 +40,12 @@ def lowrank_delta_ref(coeffs: jnp.ndarray, b: jnp.ndarray,
         if per_recv:
             pj = jnp.matmul(b[j][None].astype(jnp.float32),
                             a[:, j].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
         else:
             pj = jnp.matmul(b[j].astype(jnp.float32),
                             a[j].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)[None]
         cshape = (coeffs.shape[0],) + (1,) * (pj.ndim - 1)
         term = coeffs[:, j].reshape(cshape) * pj

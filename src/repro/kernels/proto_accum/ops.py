@@ -61,7 +61,8 @@ def proto_accumulate(f1, labels, n_classes: int, *, use_kernels=None):
         return _accum_pallas(f1.astype(jnp.float32), labels, n_classes)
     onehot = jax.nn.one_hot(labels, n_classes, dtype=jnp.float32)
     f1 = f1.astype(jnp.float32)
-    return (jnp.einsum("bc,bp->cp", onehot, f1),
+    return (jnp.einsum("bc,bp->cp", onehot, f1,
+                       precision=jax.lax.Precision.HIGHEST),
             jnp.sum(onehot, axis=0))
 
 

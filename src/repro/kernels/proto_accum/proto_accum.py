@@ -40,6 +40,7 @@ def _proto_accum_kernel(f1_ref, labels_ref, sums_ref, counts_ref, *,
     onehot = (labels == cls).astype(jnp.float32)    # [Bb, Cb], never [B, C]
     tile_sums = jax.lax.dot_general(                # [Cb, P] on the MXU
         onehot, f1, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     tile_counts = jnp.sum(onehot, axis=0)[:, None]  # [Cb, 1]
 

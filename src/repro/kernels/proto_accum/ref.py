@@ -12,6 +12,7 @@ def proto_accum_ref(f1, labels, n_classes: int):
     explicit [B, C] one-hot contraction."""
     onehot = jax.nn.one_hot(labels, n_classes, dtype=jnp.float32)
     f1 = f1.astype(jnp.float32)
-    sums = jnp.einsum("bc,bp->cp", onehot, f1)
+    sums = jnp.einsum("bc,bp->cp", onehot, f1,
+                      precision=jax.lax.Precision.HIGHEST)
     counts = jnp.sum(onehot, axis=0)
     return sums, counts

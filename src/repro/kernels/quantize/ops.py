@@ -1012,7 +1012,8 @@ def mix_packed(own, codes, row_delta, w_self, w_rows, *,
         return mix_packed_pallas(own, codes, row_delta, w_self, w_rows,
                                  interpret=_interpret())
     deq = codes.astype(jnp.float32) * row_delta[:, :, None]
-    mixed = jnp.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq)
+    mixed = jnp.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq,
+                       precision=jax.lax.Precision.HIGHEST)
     return mixed + w_self.astype(jnp.float32)[:, None, None] * \
         own.astype(jnp.float32)
 
@@ -1046,4 +1047,5 @@ def mix_packed_accumulate(acc, codes, row_delta, w_rows, *,
             jnp.ones((acc.shape[0],), jnp.float32), w_rows,
             interpret=_interpret())
     deq = codes.astype(jnp.float32) * row_delta[:, :, None]
-    return acc + jnp.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq)
+    return acc + jnp.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq,
+                            precision=jax.lax.Precision.HIGHEST)

@@ -8,11 +8,10 @@ kernels tile HBM→VMEM in (8,128)-aligned blocks (fp32 min tile).
 tensor (absmax walk, then quantize walk) with a host-side Δ round-trip
 in between.  Here one kernel does both: the grid gains a leading
 *phase* axis ``(2, nr, nc)`` — phase 0 accumulates the global abs-max
-into the (1,1) Δ output block (which stays resident across grid steps,
-acting as the reduction scratch) and finalizes Δ at the last block;
-phase 1 re-reads the tiles and writes codes.  One launch, no host
-synchronization, and the Δ block lives in registers/SMEM for the whole
-sweep:
+into the (1,1) Δ output, which lives in SMEM for the whole sweep (the
+TPU stores no scalars to VMEM) and doubles as the reduction scratch,
+and finalizes Δ at the last block; phase 1 re-reads the tiles and
+writes codes.  One launch, no host synchronization:
 
 * ``fused_quantize``            — x -> (int32 codes, Δ)
 * ``fused_quantize_dequantize`` — x -> (Q(x)·Δ fp32, Δ); the receiver-
@@ -33,9 +32,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_R = 256
 BLOCK_C = 512
+# VMEM the mix kernel's double-buffered blocks may take (the scoped
+# default on v5e is 16 MiB; the rest is left for block temporaries)
+MIX_VMEM_BUDGET = 6 << 20
 
 
 def _qmaxf(bits: int) -> float:
@@ -107,9 +110,9 @@ def _fused_call(x2d, qmax2d, *, bits: int, dequant: bool, interpret: bool):
                           (r, c, br, bc)),
         grid=(2, pl.cdiv(r, br), pl.cdiv(c, bc)),
         in_specs=[pl.BlockSpec((br, bc), lambda p, i, j: (i, j)),
-                  pl.BlockSpec((1, 1), lambda p, i, j: (0, 0))],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[pl.BlockSpec((br, bc), lambda p, i, j: (i, j)),
-                   pl.BlockSpec((1, 1), lambda p, i, j: (0, 0))],
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=[jax.ShapeDtypeStruct((r, c), out_dtype),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
         interpret=interpret,
@@ -366,7 +369,7 @@ def _mix_packed_kernel(n_nodes: int, own_ref, codes_ref, delta_ref,
     # ever materializing the fp32 neighbor payloads in HBM.
     acc = wself_ref[...][:, 0][:, None, None] * own_ref[...]
     for j in range(n_nodes):            # n_nodes is static and small
-        deq = codes_ref[j].astype(jnp.float32) * delta_ref[j][:, None]
+        deq = codes_ref[j].astype(jnp.float32) * delta_ref[j]
         acc = acc + wrows_ref[...][:, j][:, None, None] * deq[None, :, :]
     out_ref[...] = acc
 
@@ -384,7 +387,11 @@ def mix_packed_pallas(own, codes, row_delta, w_self, w_rows, *,
     """
     m, r, c = own.shape
     n = codes.shape[0]
-    br, bc = min(BLOCK_R, r), min(BLOCK_C, c)
+    bc = min(BLOCK_C, c)
+    # every receiver and sender rides each block, so the row block
+    # shrinks as M + N grows: own, codes and out blocks, double-buffered
+    row_bytes = 2 * (2 * m + n) * bc * 4
+    br = min(r, BLOCK_R, max(8, MIX_VMEM_BUDGET // row_bytes // 8 * 8))
     # fp32 "codes" (the FedAvg baseline permutes raw model buffers with
     # unit deltas) must NOT round-trip through int — only narrow wire
     # ints are upcast to the TPU-native word size
@@ -398,7 +405,7 @@ def mix_packed_pallas(own, codes, row_delta, w_self, w_rows, *,
         in_specs=[
             pl.BlockSpec((m, br, bc), lambda i, j: (0, i, j)),
             pl.BlockSpec((n, br, bc), lambda i, j: (0, i, j)),
-            pl.BlockSpec((n, br), lambda i, j: (0, i)),
+            pl.BlockSpec((n, br, 1), lambda i, j: (0, i, 0)),
             pl.BlockSpec((m, 1), lambda i, j: (0, 0)),
             pl.BlockSpec((m, n), lambda i, j: (0, 0)),
         ],
@@ -406,7 +413,8 @@ def mix_packed_pallas(own, codes, row_delta, w_self, w_rows, *,
         out_shape=jax.ShapeDtypeStruct((m, r, c), jnp.float32),
         interpret=interpret,
     )(own.astype(jnp.float32), codes,
-      row_delta.astype(jnp.float32), w_self.astype(jnp.float32).reshape(m, 1),
+      row_delta.astype(jnp.float32)[:, :, None],
+      w_self.astype(jnp.float32).reshape(m, 1),
       w_rows.astype(jnp.float32))
 
 

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape) on the
 production meshes and extract the roofline terms.
 
@@ -36,8 +32,14 @@ device moves only its row shard of the packed wire buffer, so pod-axis
 bytes stay spec-exact instead of widening to the container.  The gate
 then also asserts pod-axis collective-permute bytes/node ==
 ``predicted_node_bytes(..., "packed", inner=C)`` EXACTLY.
+
+The dry-run compiles for 512 virtual CPU devices and never touches an
+accelerator: ``main`` pins the CPU platform and the host device count
+before the first jax call, so importing this module has no side effect
+on the importing process.
 """
 import argparse
+import os
 import json
 import sys
 import traceback
@@ -467,6 +469,10 @@ def main():
                          "the ratio is recorded but not gated unless "
                          "this is set)")
     args = ap.parse_args()
+    # before the first jax call: jax fixes its platform and the host
+    # device count when its backend initializes
+    jax.config.update("jax_platforms", "cpu")
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     if args.topology is not None:
         try:

@@ -133,7 +133,7 @@ def test_overlap_rounds_stale_gossip_runs_and_learns(mnist_like):
     assert stale.extras["avg_sent_gb"] == seq.extras["avg_sent_gb"]
     assert stale.f1_per_round[-1] > 0.25
     # staleness is real: the curve diverges from the sequential one
-    # (round 0 is mix-free local training in both, later rounds differ)
+    # (round 0 is the same sequential round in both, later rounds differ)
     assert stale.f1_per_round != seq.f1_per_round
 
 

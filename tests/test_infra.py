@@ -11,6 +11,8 @@ from jax.sharding import PartitionSpec as P
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.config import get_config
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_host_mesh
+from repro.launch.wire import fed_mesh
 from repro.models import derive_student, init_params
 
 
@@ -38,12 +40,46 @@ def test_checkpoint_mismatch_raises(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# compile cache and meshes (the entry points' launch helpers)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "/some/cache"],
+                         ids=["repo-default", "from-env"])
+def test_enable_compile_cache_directory(monkeypatch, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    without it the cache goes to ``<repo>/.jax_cache``.  The config
+    update is recorded, not applied: tests never turn the cache on."""
+    from repro.launch import compile_cache as cc
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        want = str(cc.REPO_ROOT / ".jax_cache")
+        assert (cc.REPO_ROOT / "src" / "repro").is_dir()
+        assert cc.enable_compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv(cc.ENV_VAR, env_dir)
+        assert cc.enable_compile_cache() == env_dir
+        assert updates == []
+
+
+def test_launch_meshes_have_auto_axes():
+    """The round programs place state with ``with_sharding_constraint``,
+    which Explicit mesh axes (``jax.make_mesh``'s default) refuse."""
+    from jax.sharding import AxisType
+    for mesh in (make_host_mesh(), fed_mesh(1)):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+# ---------------------------------------------------------------------------
 # sharding rules
 # ---------------------------------------------------------------------------
 
 def test_param_specs_shapes_and_divisibility():
     from repro.sharding import param_specs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     cfg = get_config("yi-6b").smoke()
     shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     specs = param_specs(cfg, shapes, mesh)
@@ -60,7 +96,7 @@ def test_param_specs_shapes_and_divisibility():
 
 def test_spec_rank_matches_leaf_rank():
     from repro.sharding import param_specs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     for arch in ["grok-1-314b", "mamba2-130m", "recurrentgemma-9b",
                  "whisper-small"]:
         cfg = get_config(arch).smoke()
@@ -138,7 +174,7 @@ def test_analyze_hlo_collectives():
 def test_mesh_profe_round_math():
     from repro.core.mesh_federation import make_profe_round
     from repro.sharding import param_specs
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = fed_mesh(1)
     cfg = get_config("yi-6b").smoke()
     student_cfg = derive_student(cfg)
     s0 = init_params(student_cfg, jax.random.PRNGKey(0))
@@ -184,7 +220,7 @@ def test_mesh_masked_topology_round(topo):
     from repro.sharding import param_specs
     n = 4
     adj = T.make_schedule(n, topo, seed=0).adjacency_at(0)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = fed_mesh(1)
     cfg = get_config("yi-6b").smoke()
     student_cfg = derive_student(cfg)
     params = [init_params(student_cfg, jax.random.PRNGKey(i))

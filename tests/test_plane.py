@@ -538,9 +538,9 @@ def test_proto_ema_blends_round_two_prototypes():
                                  proto_ema=ema)[0]
         jt = jax.jit(tp, static_argnames=("teacher_on", "all_valid"))
         st = stacked if ema else stacked._replace(proto_acc=None)
-        s1, p1, c1 = jt(st, xb, valid, xb, valid, teacher_on=True,
+        s1, p1, c1, _ = jt(st, xb, valid, xb, valid, teacher_on=True,
                         all_valid=True)
-        s2, p2, c2 = jt(s1, xb, valid, xb, valid, teacher_on=True,
+        s2, p2, c2, _ = jt(s1, xb, valid, xb, valid, teacher_on=True,
                         all_valid=True)
         outs[ema] = (p1, c1, p2, c2, s2)
     p1e, c1e, p2e, c2e, s2e = outs[0.5]

@@ -1,16 +1,10 @@
-"""Property-based tests (hypothesis) on the system's invariants.
-
-Skipped cleanly when hypothesis is not installed (the container does not
-ship it); the invariants themselves are also exercised deterministically
-in test_core.py.
+"""Property-based tests (hypothesis) on the system's invariants; the
+invariants are also exercised deterministically in test_core.py.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core import distillation as D
 from repro.core import prototypes as P

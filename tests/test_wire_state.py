@@ -360,7 +360,7 @@ def _stacked_round_harness(tmp_seed=0, *, adapter_rank=0,
             node_data, train.batch_size, [fed.seed + rnd] * n_nodes, 1)
         return round_fn(state, xb, valid, pxb, pvalid,
                         w_self[0], w_neigh[0], include[0],
-                        teacher_on=True, all_valid=True)
+                        teacher_on=True, all_valid=True)[0]
 
     return stacked, run_round
 
@@ -460,7 +460,16 @@ def test_mesh_round_ef_packed_matches_gather(spec):
     for got, want in zip(_leaves(outs["packed"][:3]),
                          _leaves(outs["gather"][:3])):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-4)
-    _assert_trees_equal(outs["packed"][3], outs["gather"][3])
+    # carried residual: equal up to one ulp of the payload element.
+    # XLA:CPU contracts the residual's ``x - code*scale`` into an FMA on
+    # vector lanes but not on a leaf's scalar remainder lane, so the two
+    # programs may round the last element of the 7-wide leaf differently
+    # (a code or scale mismatch would differ by a whole quantization step)
+    got_st, want_st = outs["packed"][3], outs["gather"][3]
+    assert int(got_st.seq) == int(want_st.seq)
+    for g, w, x in zip(_leaves(got_st.residual), _leaves(want_st.residual),
+                       _leaves({"protos": protos, "student": students})):
+        assert np.all(np.abs(g - w) <= np.spacing(np.abs(x)))
     fn = make_profe_round(mesh, specs, adjacency=adj, exchange="packed",
                           spec=spec)
     with mesh:
