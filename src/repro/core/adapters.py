@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import fp32
+
 # decay on the carried gram statistic: G <- GRAM_EMA * G_prev + A^T A.
 # 0.5 halves a stale round's influence per round — enough memory to
 # smooth per-round sketch noise without freezing early-round geometry.
@@ -166,9 +168,9 @@ def factorize_delta(delta: jnp.ndarray, name: str, rank: int
     ``B = Q(QR(Δ Ω))``, ``A = Bᵀ Δ`` — so ``B @ A = Q Qᵀ Δ`` is the
     rank-``r`` projection of Δ onto the sketched column space."""
     om = _omega(name, int(delta.shape[-1]), rank)
-    y = delta @ om                                 # [..., d, r]
+    y = fp32.matmul(delta, om)                     # [..., d, r]
     q = orthonormalize(y)
-    a = jnp.swapaxes(q, -1, -2) @ delta            # [..., r, k]
+    a = fp32.matmul(jnp.swapaxes(q, -1, -2), delta)  # [..., r, k]
     return q, a
 
 
@@ -189,7 +191,7 @@ def gram_update(factors: Dict[str, Dict[str, Any]],
     out = {}
     for n, f in factors.items():
         a = f["A"]
-        g = jnp.swapaxes(a, -1, -2) @ a            # [..., k, k]
+        g = fp32.matmul(jnp.swapaxes(a, -1, -2), a)  # [..., k, k]
         if prev is not None:
             g = g + GRAM_EMA * prev[n]
         out[n] = g

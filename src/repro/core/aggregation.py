@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import fp32
+
 
 def weighted_tree_mean(trees: Sequence[Any], weights: Sequence[float]):
     w = np.asarray(weights, np.float64)
@@ -88,12 +90,12 @@ def regmean_adjust(a: jnp.ndarray, grams: jnp.ndarray,
     c32 = coeffs.astype(jnp.float32)
     if per_recv is None:
         per_recv = grams.ndim == 4
-    gsum = jnp.einsum("ns,ns...kl->n...kl" if per_recv
-                      else "ns,s...kl->n...kl", c32, g32)
+    gsum = fp32.einsum("ns,ns...kl->n...kl" if per_recv
+                       else "ns,s...kl->n...kl", c32, g32)
     tr = jnp.trace(gsum, axis1=-2, axis2=-1) / k
     gsum = gsum + (eps * tr + 1e-6)[..., None, None] * \
         jnp.eye(k, dtype=jnp.float32)
-    ag = a32 @ g32                          # [(N,) S, *lead, r, k]
+    ag = fp32.matmul(a32, g32)              # [(N,) S, *lead, r, k]
     # Gsum is symmetric: solve(Gsum_i, agᵀ)ᵀ == ag @ Gsum_i⁻¹
     if per_recv:
         x = jax.vmap(lambda g, m: jnp.linalg.solve(

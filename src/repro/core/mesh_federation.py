@@ -92,6 +92,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import fp32
 from repro.core import topology as T
 from repro.core.profe import normalize_protos
 from repro.core.prototypes import aggregate_prototypes
@@ -1204,8 +1205,8 @@ def _make_profe_round_adapter(mesh, student_specs, wire: WireSpec,
         def mixr(own, rx):
             bshape = (n,) + (1,) * (own.ndim - 1)
             return w_self_v.reshape(bshape) * own.astype(jnp.float32) + \
-                jnp.einsum("ns,ns...->n...", c_steps,
-                           rx.astype(jnp.float32))
+                fp32.einsum("ns,ns...->n...", c_steps,
+                            rx.astype(jnp.float32))
         rest_mixed = jax.tree_util.tree_map(mixr, rest_own, rest_rx)
         fac = recv["adapters"]
         mats_new = {}
@@ -1224,9 +1225,9 @@ def _make_profe_round_adapter(mesh, student_specs, wire: WireSpec,
             a4, b4 = fac[nm]["A"], fac[nm]["B"]
             if grams:
                 a4 = regmean_local(a4, recv["grams"][nm], c_steps)
-            delta = jnp.einsum("ns,ns...dr,ns...rk->n...dk", c_steps,
-                               b4.astype(jnp.float32),
-                               a4.astype(jnp.float32))
+            delta = fp32.einsum("ns,ns...dr,ns...rk->n...dk", c_steps,
+                                b4.astype(jnp.float32),
+                                a4.astype(jnp.float32))
             mats_new[nm] = mats_own[nm].astype(jnp.float32) + delta
         new_students = merge_student(layout, mats_new, rest_mixed)
         new_students = _constrain_over_pod(mesh, new_students,

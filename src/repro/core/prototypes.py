@@ -14,6 +14,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import fp32
+
 
 def local_prototypes(f1, labels, n_classes: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Eq. 3. f1: [N, P], labels: [N] int -> (protos [C, P], counts [C]).
@@ -22,8 +24,7 @@ def local_prototypes(f1, labels, n_classes: int) -> Tuple[jnp.ndarray, jnp.ndarr
     """
     onehot = jax.nn.one_hot(labels, n_classes, dtype=jnp.float32)   # [N, C]
     counts = jnp.sum(onehot, axis=0)                                # [C]
-    sums = jnp.einsum("nc,np->cp", onehot, f1.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+    sums = fp32.einsum("nc,np->cp", onehot, f1.astype(jnp.float32))
     protos = sums / jnp.maximum(counts, 1.0)[:, None]
     return protos, counts
 
@@ -43,8 +44,7 @@ def aggregate_prototypes(protos, counts) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """
     n_j = jnp.sum(counts, axis=0)                                   # [C]
     w = counts / jnp.maximum(n_j, 1.0)[None, :]                     # [M, C]
-    glob = jnp.einsum("mc,mcp->cp", w, protos.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+    glob = fp32.einsum("mc,mcp->cp", w, protos.astype(jnp.float32))
     mask = (n_j > 0).astype(jnp.float32)
     return glob, mask
 
@@ -54,8 +54,7 @@ def aggregate_prototypes_strict(protos, counts) -> Tuple[jnp.ndarray, jnp.ndarra
     n_j = jnp.sum(counts, axis=0)
     nodes_knowing = jnp.sum((counts > 0).astype(jnp.float32), axis=0)
     w = counts / jnp.maximum(n_j, 1.0)[None, :]
-    glob = jnp.einsum("mc,mcp->cp", w, protos.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+    glob = fp32.einsum("mc,mcp->cp", w, protos.astype(jnp.float32))
     glob = glob / jnp.maximum(nodes_knowing, 1.0)[:, None]
     mask = (n_j > 0).astype(jnp.float32)
     return glob, mask

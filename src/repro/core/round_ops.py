@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import fp32
 from repro.core.quantization import _INT_DTYPES, _qmax
 from repro.wirespec import WireSpec
 
@@ -231,8 +232,7 @@ def mix_node_trees(w_self, w_neigh, own_tree, recv_tree):
     """
     def mix(own, recv):
         recv32 = recv.astype(jnp.float32)
-        mixed = jnp.tensordot(w_neigh, recv32, axes=1,
-                              precision=jax.lax.Precision.HIGHEST)
+        mixed = fp32.tensordot(w_neigh, recv32, axes=1)
         bshape = (own.shape[0],) + (1,) * (own.ndim - 1)
         mixed = mixed + w_self.reshape(bshape) * own.astype(jnp.float32)
         return mixed.astype(own.dtype)
@@ -245,8 +245,7 @@ def weighted_node_mean(w, tree):
     special case used by the TPU path)."""
     w32 = w.astype(jnp.float32)
     return jax.tree_util.tree_map(
-        lambda x: jnp.tensordot(w32, x.astype(jnp.float32), axes=1,
-                               precision=jax.lax.Precision.HIGHEST), tree)
+        lambda x: fp32.tensordot(w32, x.astype(jnp.float32), axes=1), tree)
 
 
 # ---------------------------------------------------------------------------
@@ -274,8 +273,7 @@ def neighborhood_prototype_aggregate(include, protos, counts):
     eff = include[:, :, None] * counts[None, :, :]          # [N, N, C]
     n_j = jnp.sum(eff, axis=1)                              # [N, C]
     w = eff / jnp.maximum(n_j, 1.0)[:, None, :]             # [N, N, C]
-    glob = jnp.einsum("ijc,jcp->icp", w, protos.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+    glob = fp32.einsum("ijc,jcp->icp", w, protos.astype(jnp.float32))
     mask = (n_j > 0).astype(jnp.float32)
     return glob, mask
 

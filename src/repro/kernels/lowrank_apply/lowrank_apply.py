@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import fp32
+
 BLOCK_D = 256
 BLOCK_K = 512
 
@@ -46,9 +48,8 @@ def _apply_kernel(n_send: int, per_recv: bool, w_ref, c_ref, b_ref, a_ref,
     acc = None
     for j in range(n_send):
         aj = a_ref[0, j] if per_recv else a_ref[j]
-        term = c_ref[i, j] * jnp.dot(
+        term = c_ref[i, j] * fp32.dot(
             b_ref[j].astype(jnp.float32), aj.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         acc = term if acc is None else acc + term
     out_ref[0] = w_ref[0].astype(jnp.float32) + acc

@@ -19,8 +19,9 @@ the fused plane sweep exists to avoid.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
+
+from repro import fp32
 
 
 def lowrank_delta_ref(coeffs: jnp.ndarray, b: jnp.ndarray,
@@ -38,15 +39,13 @@ def lowrank_delta_ref(coeffs: jnp.ndarray, b: jnp.ndarray,
     delta = None
     for j in range(n_send):
         if per_recv:
-            pj = jnp.matmul(b[j][None].astype(jnp.float32),
-                            a[:, j].astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST,
-                            preferred_element_type=jnp.float32)
+            pj = fp32.matmul(b[j][None].astype(jnp.float32),
+                             a[:, j].astype(jnp.float32),
+                             preferred_element_type=jnp.float32)
         else:
-            pj = jnp.matmul(b[j].astype(jnp.float32),
-                            a[j].astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST,
-                            preferred_element_type=jnp.float32)[None]
+            pj = fp32.matmul(b[j].astype(jnp.float32),
+                             a[j].astype(jnp.float32),
+                             preferred_element_type=jnp.float32)[None]
         cshape = (coeffs.shape[0],) + (1,) * (pj.ndim - 1)
         term = coeffs[:, j].reshape(cshape) * pj
         delta = term if delta is None else delta + term

@@ -23,6 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import fp32
 from repro.kernels.proto_accum.proto_accum import (BLOCK_B, BLOCK_C,
                                                    proto_accum_pallas)
 
@@ -61,8 +62,7 @@ def proto_accumulate(f1, labels, n_classes: int, *, use_kernels=None):
         return _accum_pallas(f1.astype(jnp.float32), labels, n_classes)
     onehot = jax.nn.one_hot(labels, n_classes, dtype=jnp.float32)
     f1 = f1.astype(jnp.float32)
-    return (jnp.einsum("bc,bp->cp", onehot, f1,
-                       precision=jax.lax.Precision.HIGHEST),
+    return (fp32.einsum("bc,bp->cp", onehot, f1),
             jnp.sum(onehot, axis=0))
 
 

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import fp32
+
 BLOCK_B = 128
 BLOCK_C = 128
 
@@ -38,9 +40,8 @@ def _proto_accum_kernel(f1_ref, labels_ref, sums_ref, counts_ref, *,
     cls = jax.lax.broadcasted_iota(jnp.int32, (f1.shape[0], block_c), 1) \
         + ci * block_c
     onehot = (labels == cls).astype(jnp.float32)    # [Bb, Cb], never [B, C]
-    tile_sums = jax.lax.dot_general(                # [Cb, P] on the MXU
+    tile_sums = fp32.dot_general(                   # [Cb, P] on the MXU
         onehot, f1, (((0,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     tile_counts = jnp.sum(onehot, axis=0)[:, None]  # [Cb, 1]
 

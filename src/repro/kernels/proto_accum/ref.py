@@ -6,13 +6,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import fp32
+
 
 def proto_accum_ref(f1, labels, n_classes: int):
     """f1: [B, P], labels: [B] -> (sums [C, P], counts [C]) via the
     explicit [B, C] one-hot contraction."""
     onehot = jax.nn.one_hot(labels, n_classes, dtype=jnp.float32)
     f1 = f1.astype(jnp.float32)
-    sums = jnp.einsum("bc,bp->cp", onehot, f1,
-                      precision=jax.lax.Precision.HIGHEST)
+    sums = fp32.einsum("bc,bp->cp", onehot, f1)
     counts = jnp.sum(onehot, axis=0)
     return sums, counts

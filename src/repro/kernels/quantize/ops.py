@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import fp32
 from repro.kernels.quantize.quantize import (dequantize_pallas,
                                              dequantize_rows_pallas,
                                              fused_quantize_dequantize_pallas,
@@ -1012,8 +1013,7 @@ def mix_packed(own, codes, row_delta, w_self, w_rows, *,
         return mix_packed_pallas(own, codes, row_delta, w_self, w_rows,
                                  interpret=_interpret())
     deq = codes.astype(jnp.float32) * row_delta[:, :, None]
-    mixed = jnp.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq,
-                       precision=jax.lax.Precision.HIGHEST)
+    mixed = fp32.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq)
     return mixed + w_self.astype(jnp.float32)[:, None, None] * \
         own.astype(jnp.float32)
 
@@ -1047,5 +1047,5 @@ def mix_packed_accumulate(acc, codes, row_delta, w_rows, *,
             jnp.ones((acc.shape[0],), jnp.float32), w_rows,
             interpret=_interpret())
     deq = codes.astype(jnp.float32) * row_delta[:, :, None]
-    return acc + jnp.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32), deq,
-                            precision=jax.lax.Precision.HIGHEST)
+    return acc + fp32.einsum("mn,nrc->mrc", w_rows.astype(jnp.float32),
+                             deq)

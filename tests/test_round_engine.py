@@ -161,6 +161,55 @@ def test_neighborhood_prototype_aggregate_matches_eq4():
                                       np.asarray(want_mask))
 
 
+def _fp32_contractions():
+    """Wire, gossip and merge contractions of every engine, each as
+    ``(fn, operands)`` at toy shapes."""
+    from repro.core.adapters import factorize_delta
+    from repro.core.aggregation import regmean_adjust
+    from repro.core.prototypes import local_prototypes
+    from repro.kernels.lowrank_apply.ref import lowrank_delta_ref
+    from repro.kernels.proto_accum.ref import proto_accum_ref
+    from repro.kernels.quantize.ops import mix_packed
+    n, c, p = 3, 4, 8
+    f = jnp.ones((n, c, p), jnp.float32)
+    w, wn = jnp.ones((n,)), jnp.ones((n, n))
+    grams = jnp.ones((n, p, p)) + jnp.eye(p)
+    labels = jnp.arange(6) % c
+    return {
+        "mix_node_trees": (R.mix_node_trees, (w, wn, {"a": f}, {"a": f})),
+        "weighted_node_mean": (R.weighted_node_mean, (w, {"a": f})),
+        "eq4": (R.neighborhood_prototype_aggregate,
+                (wn, f, jnp.ones((n, c)))),
+        "eq3": (lambda x, y: local_prototypes(x, y, c),
+                (jnp.ones((6, p)), labels)),
+        "eq3_ref": (lambda x, y: proto_accum_ref(x, y, c),
+                    (jnp.ones((6, p)), labels)),
+        "mix_packed": (lambda o, q, d, ws, wr: mix_packed(
+            o, q, d, ws, wr, use_kernels=False),
+            (f, jnp.ones((n, c, p), jnp.int16), jnp.ones((n, c)), w, wn)),
+        "sketch": (lambda d: factorize_delta(d, "w", 2), (f[0],)),
+        "regmean": (lambda a, g, cf: regmean_adjust(a, g, cf,
+                                                    per_recv=False),
+                    (jnp.ones((n, 2, p)), grams, wn)),
+        "lowrank_delta": (lowrank_delta_ref,
+                          (wn, jnp.ones((n, c, 2)), jnp.ones((n, 2, p)))),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_fp32_contractions()))
+def test_merge_contractions_ask_for_fp32(name):
+    """Every contraction of the wire, gossip and merge math asks for
+    ``Precision.HIGHEST`` (through ``repro.fp32``): at the default
+    precision the TPU would round its fp32 operands to bf16, and the
+    engines would stop agreeing on the chip."""
+    fn, args = _fp32_contractions()[name]
+    dots = [ln for ln in jax.jit(fn).lower(*args).as_text().splitlines()
+            if "stablehlo.dot_general" in ln]
+    assert dots, f"{name} lowers to no dot_general"
+    for ln in dots:
+        assert "precision = [HIGHEST, HIGHEST]" in ln, ln
+
+
 def test_per_node_quantization_matches_per_tensor():
     """One scale per node slice == quantizing each node's tensor alone."""
     stacked = jnp.asarray(RNG.standard_normal((3, 17, 9)) * 5, jnp.float32)
