@@ -1,0 +1,7 @@
+"""Share of the traced window in which the device is idle while the host
+is inside a ``fed.stage`` span (staging a round's batches), in %."""
+from bench.scopes import idle_share
+
+
+def read(ctx):
+    return idle_share(ctx, "fed.stage")

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.config.base import FederationConfig, ModelConfig, TrainConfig
 from repro.core import baselines as B
 from repro.core import round_ops as R
@@ -364,13 +365,6 @@ def _masked_select(v, new_tree, old_tree):
 # the jitted round program
 # ---------------------------------------------------------------------------
 
-# Trace bookkeeping for the fused Eq. 3 scan body: incremented only
-# when jax (re)traces the fused training scan, so tests can assert the
-# fused round compiles a bounded number of times regardless of how many
-# rounds run (the fused pass must not reintroduce per-round retracing).
-FUSED_PROTO_TRACES: Dict[Tuple[str, int], int] = {}
-
-
 def _make_proto_pass(proto_cfg: ModelConfig, ncls: int):
     """The exact (post-training) Eq. 3 pass over a stacked ``[T, N, B,
     ...]`` proto batch stream: scan over T, vmap the forward over nodes,
@@ -382,6 +376,7 @@ def _make_proto_pass(proto_cfg: ModelConfig, ncls: int):
     ``benchmarks/round_step.py --phases`` can jit and time this pass in
     isolation (the "proto" phase of the exact round)."""
 
+    @jax.named_scope("round.protos")
     def proto_pass(students, pxb, pvalid):
         students = as_tree(students)   # plane buffers forward as views
         proto_dim = proto_cfg.proto_dim
@@ -465,7 +460,6 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
     fused = share_protos and proto_pass == "fused"
     exact_pass = _make_proto_pass(proto_cfg, ncls) \
         if share_protos and not fused else None
-    trace_key = (proto_cfg.name, ncls)
 
     def train_phase(state: NodeState, xb, valid, pxb, pvalid,
                     teacher_on: bool, all_valid: bool = False):
@@ -489,17 +483,16 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
                 counts0 = jnp.zeros((n_nodes, ncls), jnp.float32)
 
             def fbody(carry, inp):
-                FUSED_PROTO_TRACES[trace_key] = \
-                    FUSED_PROTO_TRACES.get(trace_key, 0) + 1
                 st, sums, counts = carry
                 batch, v = inp
                 new, m = jax.vmap(
                     lambda s, b: step(s, b, teacher_on))(st, batch)
-                labels = proto_labels(proto_cfg, batch)    # [N, B]
-                s_add, c_add = proto_accumulate_nodes(m["f1"], labels,
-                                                      ncls)
-                sums = sums + s_add * v[:, None, None]
-                counts = counts + c_add * v[:, None]
+                with jax.named_scope("round.protos"):
+                    labels = proto_labels(proto_cfg, batch)    # [N, B]
+                    s_add, c_add = proto_accumulate_nodes(m["f1"], labels,
+                                                          ncls)
+                    sums = sums + s_add * v[:, None, None]
+                    counts = counts + c_add * v[:, None]
                 st = new if all_valid else _masked_select(v, new, st)
                 return (st, sums, counts), m["loss_s"]
 
@@ -509,8 +502,9 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
             state = state._replace(round_idx=state.round_idx + 1)
             if proto_ema and proto_ema > 0:
                 state = state._replace(proto_acc=(sums, counts))
-            return (state, normalize_protos(sums, counts), counts,
-                    _mean_loss(losses, valid))
+            with jax.named_scope("round.protos"):
+                protos = normalize_protos(sums, counts)
+            return state, protos, counts, _mean_loss(losses, valid)
 
         def body(carry, inp):
             batch, v = inp
@@ -528,12 +522,15 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
         # 2) Eq. 3 prototype accumulation: the factored exact pass
         #    (post-training student forward over the proto stream)
         sums, counts = exact_pass(state.student, pxb, pvalid)
-        if proto_ema and proto_ema > 0:
-            sums = sums + proto_ema * state.proto_acc[0]
-            counts = counts + proto_ema * state.proto_acc[1]
-            state = state._replace(proto_acc=(sums, counts))
-        return state, normalize_protos(sums, counts), counts, loss
+        with jax.named_scope("round.protos"):
+            if proto_ema and proto_ema > 0:
+                sums = sums + proto_ema * state.proto_acc[0]
+                counts = counts + proto_ema * state.proto_acc[1]
+                state = state._replace(proto_acc=(sums, counts))
+            protos = normalize_protos(sums, counts)
+        return state, protos, counts, loss
 
+    @jax.named_scope("round.codec")
     def share_phase(state: NodeState, protos):
         # 3a) the wire: receiver-side reconstruction.  A node's own
         #    model copy never crosses it (mixes unquantized);
@@ -591,6 +588,7 @@ def _make_round_parts(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
             (protos if share_protos else ()))
         return state, recv_student, protos_rx
 
+    @jax.named_scope("round.mix")
     def mix_phase(state: NodeState, recv_student, protos_rx, counts,
                   w_self, w_neigh, include) -> NodeState:
         # 3b) gossip + aggregation (shared round_ops core)
@@ -820,7 +818,26 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
       into share ``t`` is the one produced by share ``t-1`` (asserted
       across carried rounds in tests).  A run of R >= 2 rounds applies
       R-1 mixes; the final round's payload is shared but never consumed.
+
+    The driver's steps are :mod:`repro.spans` spans: ``fed.run`` around
+    the call, ``fed.init`` around the node state's set-up, ``fed.round``
+    (a profiler step) around each round, and inside it ``fed.stage``,
+    ``fed.dispatch``, ``fed.meter``, ``fed.eval`` and ``fed.sync``.
     """
+    with spans.span("fed.run"):
+        return _run_federation(teacher_cfg, fed, train, node_data,
+                               test_data, verbose=verbose,
+                               eval_all_nodes=eval_all_nodes,
+                               overlap=overlap,
+                               stale_self_floor=stale_self_floor)
+
+
+def _run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
+                    train: TrainConfig,
+                    node_data: List[Dict[str, np.ndarray]],
+                    test_data: Dict[str, np.ndarray], *, verbose: bool,
+                    eval_all_nodes: bool, overlap: Optional[str],
+                    stale_self_floor: Optional[float]) -> FederationResult:
     if overlap not in OVERLAPS:
         raise ValueError(f"overlap must be one of {OVERLAPS}, "
                          f"got {overlap!r}")
@@ -861,48 +878,51 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
 
     # stage round 0's batches up front so raggedness is known before any
     # state is allocated (fallback keeps the per-node reference path)
-    probe = _stack_round_batches(
-        node_data, train.batch_size,
-        [fed.seed + 0 * 997 + i for i in range(n_nodes)], fed.local_epochs)
+    with spans.span("fed.stage"):
+        probe = _stack_round_batches(
+            node_data, train.batch_size,
+            [fed.seed + 0 * 997 + i for i in range(n_nodes)],
+            fed.local_epochs)
     if probe is None:
         return run_federation_loop(teacher_cfg, fed, train, node_data,
                                    test_data, verbose=verbose,
                                    eval_all_nodes=eval_all_nodes)
 
     meter = ScheduleCommAccountant(sched)
-    stacked = _stack_states(
-        _init_states(algo, model_cfgs, fed, opt_s, opt_t, ncls,
-                     plane=use_plane))
     eval_cfg = model_cfgs[1] if algo in ("profe", "fml") else model_cfgs[0]
     proto_cfg = eval_cfg
     needs_teacher = algo in ("profe", "fml")
     adapters_on = bool(fed.adapter_rank) and wire_model is not None \
         and share_protos and isinstance(bits, WireSpec)
-    if adapters_on:
-        # adapter-rank wire: the per-node reference snapshot (and gram
-        # carry) rides the stacked NodeState through the jitted round
-        from repro.core.adapters import adapter_layout, init_adapter_state
-        a_layout = adapter_layout(as_tree(stacked.student),
-                                  fed.adapter_rank, node_axis=True)
-        stacked = stacked._replace(adapter_state=init_adapter_state(
-            a_layout, as_tree(stacked.student), grams=fed.adapter_grams))
-    if isinstance(bits, WireSpec) and bits.error_feedback:
-        # stateful codec: zero residual per node, shaped like the wire
-        # payload — carried inside the stacked NodeState from here on
-        from repro.core.wire_state import init_codec_state
-        ef_payload = {"protos": jnp.zeros(
-            (n_nodes, ncls, proto_cfg.proto_dim), jnp.float32)}
+    with spans.span("fed.init"):
+        stacked = _stack_states(
+            _init_states(algo, model_cfgs, fed, opt_s, opt_t, ncls,
+                         plane=use_plane))
         if adapters_on:
-            # the residual mirrors the adapter payload structure:
-            # factor-shaped zeros + the dense rest (+ gram zeros)
-            from repro.core.adapters import zero_wire_payload
-            ef_payload.update(zero_wire_payload(
-                a_layout, as_tree(stacked.student),
-                grams=fed.adapter_grams))
-        else:
-            ef_payload["student"] = stacked.student
-        stacked = stacked._replace(
-            wire_state=init_codec_state(ef_payload, n_nodes=n_nodes))
+            # adapter-rank wire: the per-node reference snapshot (and gram
+            # carry) rides the stacked NodeState through the jitted round
+            from repro.core.adapters import adapter_layout, init_adapter_state
+            a_layout = adapter_layout(as_tree(stacked.student),
+                                      fed.adapter_rank, node_axis=True)
+            stacked = stacked._replace(adapter_state=init_adapter_state(
+                a_layout, as_tree(stacked.student), grams=fed.adapter_grams))
+        if isinstance(bits, WireSpec) and bits.error_feedback:
+            # stateful codec: zero residual per node, shaped like the wire
+            # payload — carried inside the stacked NodeState from here on
+            from repro.core.wire_state import init_codec_state
+            ef_payload = {"protos": jnp.zeros(
+                (n_nodes, ncls, proto_cfg.proto_dim), jnp.float32)}
+            if adapters_on:
+                # the residual mirrors the adapter payload structure:
+                # factor-shaped zeros + the dense rest (+ gram zeros)
+                from repro.core.adapters import zero_wire_payload
+                ef_payload.update(zero_wire_payload(
+                    a_layout, as_tree(stacked.student),
+                    grams=fed.adapter_grams))
+            else:
+                ef_payload["student"] = stacked.student
+            stacked = stacked._replace(
+                wire_state=init_codec_state(ef_payload, n_nodes=n_nodes))
 
     # the lowered schedule: [R, N]/[R, N, N] stacks indexed per round and
     # fed to the jitted round as traced operands (R == 1 for static)
@@ -954,7 +974,28 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
     losses: List[float] = []
     result.extras["loss_per_round"] = losses
     result.extras["engine"] = "stacked"
-    t0 = time.time()
+
+    def finish_round(rnd: int, loss, tag: str) -> None:
+        # metering is analytic and vectorized — per-copy bytes from the
+        # payload skeleton times the schedule's degree vectors,
+        # byte-identical to the reference loop's per-edge meter
+        with spans.span("fed.meter"):
+            meter.record_round(payload, kind=algo, round_idx=rnd, bits=bits)
+        with spans.span("fed.eval"):
+            students = as_tree(stacked.student)
+            f1, acc = _eval_nodes(eval_cfg,
+                                  lambda i: _node_slice(students, i),
+                                  n_nodes, test_data, eval_all_nodes,
+                                  result.extras,
+                                  stacked_students=students)
+        result.f1_per_round.append(f1)
+        result.acc_per_round.append(acc)
+        with spans.span("fed.sync"):
+            losses.append(float(loss))
+        if verbose:
+            print(f"[{tag}] round {rnd + 1}/{fed.rounds} "
+                  f"f1={f1:.4f} acc={acc:.4f} "
+                  f"sent={meter.avg_sent_gb():.4f}GB")
 
     empty = ({}, jnp.zeros((0, n_nodes), jnp.float32))
     if overlap is not None:
@@ -965,117 +1006,89 @@ def run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
             adapter_rank=fed.adapter_rank if adapters_on else 0,
             adapter_grams=fed.adapter_grams)
         staged_next = probe
-        proto_next = _stack_round_batches(
-            node_data, train.batch_size, [fed.seed] * n_nodes, 1) \
-            if stream_protos else empty
+        with spans.span("fed.stage"):
+            proto_next = _stack_round_batches(
+                node_data, train.batch_size, [fed.seed] * n_nodes, 1) \
+                if stream_protos else empty
         recv_prev = None
         for rnd in range(fed.rounds):
-            t_r = time.time()
-            t_on = teacher_active(fed.alpha_s, fed.alpha_limit, rnd) \
-                if algo == "profe" else needs_teacher
-            xb, valid = staged_next
-            pxb, pvalid = proto_next
-            p = sched.phase_index(rnd)
-            stacked, protos, counts, loss = train_jit(
-                stacked, xb, valid, pxb, pvalid, teacher_on=t_on,
-                all_valid=bool(np.all(np.asarray(valid) == 1.0)))
-            if overlap == "rounds" and rnd > 0:
-                # stale-by-one: mix the payload shared LAST round into
-                # this round's trained state, then share this round's
-                # payload — its consumption waits until round t+1, so
-                # the device runs it concurrently with whatever the
-                # host (and the next round's training) does meanwhile.
-                # Round 0 mixes synchronously (the else branch): nodes
-                # start from independent initializations, and a stale
-                # mix of unaligned models collapses the first rounds
-                if recv_prev is not None:
-                    stacked = mix_jit(stacked, *recv_prev, w_self_st[p],
-                                      w_neigh_st[p], include_st[p])
-                stacked, recv_student, protos_rx = share_jit(stacked,
-                                                             protos)
-                recv_prev = (recv_student, protos_rx, counts)
-            else:
-                stacked, recv_student, protos_rx = share_jit(stacked,
-                                                             protos)
-                stacked = mix_jit(stacked, recv_student, protos_rx,
-                                  counts, w_self_st[p], w_neigh_st[p],
-                                  include_st[p])
-            # round t's phase programs are dispatched, not finished
-            # (JAX async dispatch): stage round t+1's batches on the
-            # host while the device runs them — the pipeline's
-            # host/device overlap, and the measured critical-path win
-            if rnd + 1 < fed.rounds:
-                staged_next = _stack_round_batches(
-                    node_data, train.batch_size,
-                    [fed.seed + (rnd + 1) * 997 + i
-                     for i in range(n_nodes)], fed.local_epochs)
-                assert staged_next is not None  # raggedness is static
-                proto_next = _stack_round_batches(
-                    node_data, train.batch_size,
-                    [fed.seed + rnd + 1] * n_nodes, 1) \
-                    if stream_protos else empty
-            meter.record_round(payload, kind=algo, round_idx=rnd,
-                               bits=bits)
-            students = as_tree(stacked.student)
-            f1, acc = _eval_nodes(eval_cfg,
-                                  lambda i: _node_slice(students, i),
-                                  n_nodes, test_data, eval_all_nodes,
-                                  result.extras,
-                                  stacked_students=students)
-            result.f1_per_round.append(f1)
-            result.acc_per_round.append(acc)
-            losses.append(float(loss))
-            round_times.append(time.time() - t_r)
-            if verbose:
-                print(f"[{algo}/overlap={overlap}] round "
-                      f"{rnd + 1}/{fed.rounds} f1={f1:.4f} acc={acc:.4f} "
-                      f"sent={meter.avg_sent_gb():.4f}GB")
-        result.elapsed_s = time.time() - t0
-        result.extras["avg_sent_gb"] = meter.avg_sent_gb()
-        result.extras["avg_received_gb"] = meter.avg_received_gb()
-        return result
+            with spans.span("fed.round", step=rnd) as this_round:
+                t_on = teacher_active(fed.alpha_s, fed.alpha_limit, rnd) \
+                    if algo == "profe" else needs_teacher
+                xb, valid = staged_next
+                pxb, pvalid = proto_next
+                p = sched.phase_index(rnd)
+                with spans.span("fed.dispatch"):
+                    stacked, protos, counts, loss = train_jit(
+                        stacked, xb, valid, pxb, pvalid, teacher_on=t_on,
+                        all_valid=bool(np.all(np.asarray(valid) == 1.0)))
+                    if overlap == "rounds" and rnd > 0:
+                        # stale-by-one: mix the payload shared LAST round
+                        # into this round's trained state, then share this
+                        # round's payload — its consumption waits until
+                        # round t+1, so the device runs it concurrently
+                        # with whatever the host (and the next round's
+                        # training) does meanwhile.  Round 0 mixes
+                        # synchronously (the else branch): nodes start
+                        # from independent initializations, and a stale
+                        # mix of unaligned models collapses the first
+                        # rounds
+                        if recv_prev is not None:
+                            stacked = mix_jit(stacked, *recv_prev,
+                                              w_self_st[p], w_neigh_st[p],
+                                              include_st[p])
+                        stacked, recv_student, protos_rx = share_jit(
+                            stacked, protos)
+                        recv_prev = (recv_student, protos_rx, counts)
+                    else:
+                        stacked, recv_student, protos_rx = share_jit(
+                            stacked, protos)
+                        stacked = mix_jit(stacked, recv_student, protos_rx,
+                                          counts, w_self_st[p],
+                                          w_neigh_st[p], include_st[p])
+                # round t's phase programs are dispatched, not finished
+                # (JAX async dispatch): stage round t+1's batches on the
+                # host while the device runs them — the pipeline's
+                # host/device overlap, and the measured critical-path win
+                if rnd + 1 < fed.rounds:
+                    with spans.span("fed.stage"):
+                        staged_next = _stack_round_batches(
+                            node_data, train.batch_size,
+                            [fed.seed + (rnd + 1) * 997 + i
+                             for i in range(n_nodes)], fed.local_epochs)
+                        assert staged_next is not None  # raggedness is static
+                        proto_next = _stack_round_batches(
+                            node_data, train.batch_size,
+                            [fed.seed + rnd + 1] * n_nodes, 1) \
+                            if stream_protos else empty
+                finish_round(rnd, loss, f"{algo}/overlap={overlap}")
+            round_times.append(this_round.seconds)
+    else:
+        for rnd in range(fed.rounds):
+            with spans.span("fed.round", step=rnd) as this_round:
+                t_on = teacher_active(fed.alpha_s, fed.alpha_limit, rnd) \
+                    if algo == "profe" else needs_teacher
+                with spans.span("fed.stage"):
+                    staged = probe if rnd == 0 else _stack_round_batches(
+                        node_data, train.batch_size,
+                        [fed.seed + rnd * 997 + i for i in range(n_nodes)],
+                        fed.local_epochs)
+                    proto_staged = _stack_round_batches(
+                        node_data, train.batch_size,
+                        [fed.seed + rnd] * n_nodes, 1) \
+                        if stream_protos else empty
+                xb, valid = staged
+                pxb, pvalid = proto_staged
+                p = sched.phase_index(rnd)
+                with spans.span("fed.dispatch"):
+                    stacked, loss = round_fn(
+                        stacked, xb, valid, pxb, pvalid, w_self_st[p],
+                        w_neigh_st[p], include_st[p], teacher_on=t_on,
+                        all_valid=bool(np.all(np.asarray(valid) == 1.0)))
+                finish_round(rnd, loss, algo)
+            round_times.append(this_round.seconds)
 
-    for rnd in range(fed.rounds):
-        t_r = time.time()
-        t_on = teacher_active(fed.alpha_s, fed.alpha_limit, rnd) \
-            if algo == "profe" else needs_teacher
-        staged = probe if rnd == 0 else _stack_round_batches(
-            node_data, train.batch_size,
-            [fed.seed + rnd * 997 + i for i in range(n_nodes)],
-            fed.local_epochs)
-        proto_staged = _stack_round_batches(
-            node_data, train.batch_size, [fed.seed + rnd] * n_nodes, 1) \
-            if stream_protos else empty
-        xb, valid = staged
-        pxb, pvalid = proto_staged
-
-        p = sched.phase_index(rnd)
-        stacked, loss = round_fn(
-            stacked, xb, valid, pxb, pvalid, w_self_st[p], w_neigh_st[p],
-            include_st[p], teacher_on=t_on,
-            all_valid=bool(np.all(np.asarray(valid) == 1.0)))
-
-        # metering is analytic and vectorized — per-copy bytes from the
-        # payload skeleton times the schedule's degree vectors,
-        # byte-identical to the reference loop's per-edge meter
-        meter.record_round(payload, kind=algo, round_idx=rnd, bits=bits)
-
-        students = as_tree(stacked.student)
-        f1, acc = _eval_nodes(eval_cfg,
-                              lambda i: _node_slice(students, i),
-                              n_nodes, test_data, eval_all_nodes,
-                              result.extras,
-                              stacked_students=students)
-        result.f1_per_round.append(f1)
-        result.acc_per_round.append(acc)
-        losses.append(float(loss))
-        round_times.append(time.time() - t_r)
-        if verbose:
-            print(f"[{algo}] round {rnd + 1}/{fed.rounds} "
-                  f"f1={f1:.4f} acc={acc:.4f} "
-                  f"sent={meter.avg_sent_gb():.4f}GB")
-
-    result.elapsed_s = time.time() - t0
+    result.elapsed_s = sum(round_times)
     result.extras["avg_sent_gb"] = meter.avg_sent_gb()
     result.extras["avg_received_gb"] = meter.avg_received_gb()
     return result

@@ -131,9 +131,11 @@ def make_profe_step(teacher_cfg: ModelConfig, student_cfg: ModelConfig,
                 l = l + out.aux * getattr(teacher_cfg, "router_aux_weight", 0.0)
                 return l, out
 
-            (lt, teacher_out), gt = jax.value_and_grad(t_loss, has_aux=True)(teacher)
-            gt, _ = clip_by_global_norm(gt, grad_clip)
-            teacher, opt_t_state = opt_t.update(gt, opt_t_state, teacher)
+            with jax.named_scope("round.teacher"):
+                (lt, teacher_out), gt = jax.value_and_grad(
+                    t_loss, has_aux=True)(teacher)
+                gt, _ = clip_by_global_norm(gt, grad_clip)
+                teacher, opt_t_state = opt_t.update(gt, opt_t_state, teacher)
             metrics["loss_t"] = lt
             teacher_out = jax.tree_util.tree_map(jax.lax.stop_gradient,
                                                  teacher_out)
@@ -148,17 +150,19 @@ def make_profe_step(teacher_cfg: ModelConfig, student_cfg: ModelConfig,
                                 state.proto_mask, alpha, fed.beta_s,
                                 fed.kd_temperature, teacher_out, remat=remat)
 
-        (ls, out_s), gs = jax.value_and_grad(s_loss, has_aux=True)(state.student)
-        if isinstance(state.student, Plane):
-            # fused path: the plane optimizer clips + updates in one
-            # sweep over the buffer and reports the pre-clip norm
-            student, opt_s_state = opt_s.update(gs, state.opt_s,
-                                                state.student)
-            gnorm = opt_s_state["gnorm"]
-        else:
-            gs, gnorm = clip_by_global_norm(gs, grad_clip)
-            student, opt_s_state = opt_s.update(gs, state.opt_s,
-                                                state.student)
+        with jax.named_scope("round.student"):
+            (ls, out_s), gs = jax.value_and_grad(s_loss, has_aux=True)(
+                state.student)
+            if isinstance(state.student, Plane):
+                # fused path: the plane optimizer clips + updates in one
+                # sweep over the buffer and reports the pre-clip norm
+                student, opt_s_state = opt_s.update(gs, state.opt_s,
+                                                    state.student)
+                gnorm = opt_s_state["gnorm"]
+            else:
+                gs, gnorm = clip_by_global_norm(gs, grad_clip)
+                student, opt_s_state = opt_s.update(gs, state.opt_s,
+                                                    state.student)
         # the f1 the loss already computed rides out in metrics so the
         # fused Eq. 3 pass (proto_pass="fused") can accumulate it
         # without a second forward; exact mode never reads it (DCE'd)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import spans
 from repro.config import FederationConfig, TrainConfig, get_config
 from repro.core import federation as F
 from repro.core import profe
@@ -152,20 +153,20 @@ def test_fused_stacked_matches_fused_loop(mnist_like):
 
 def test_fused_scan_body_traces_rounds_independent(mnist_like):
     """The fused training scan must not reintroduce per-round
-    retracing: its body trace count after a 3-round run equals the
-    count after a 1-round run (rounds <= 4 keeps ``teacher_on`` static
-    across rounds, so there is exactly one program variant)."""
+    retracing: the round program's trace count under ``fed.dispatch``
+    after a 3-round run equals the count after a 1-round run (rounds <=
+    4 keeps ``teacher_on`` static across rounds, so there is exactly one
+    program variant)."""
     cfg, node_data, test_d = mnist_like
     counts = {}
     for rounds in (1, 3):
-        F.FUSED_PROTO_TRACES.clear()
+        spans.reset()
         fed = FederationConfig(num_nodes=N_NODES, rounds=rounds,
                                local_epochs=1, algorithm="profe",
                                proto_pass="fused")
         run_federation(cfg, fed, TRAIN, node_data, test_d)
-        key = (derive_student(cfg).name, cfg.num_classes)
-        counts[rounds] = F.FUSED_PROTO_TRACES[key]
-    assert counts[3] == counts[1], counts
+        counts[rounds] = spans.counters()["fed.dispatch.traces"]
+    assert counts[1] >= 1 and counts[3] == counts[1], counts
 
 
 def test_invalid_proto_pass_rejected(mnist_like):

@@ -178,3 +178,25 @@ def test_fused_quantize_compiles(one_chip, rows):
     _compile(one_chip,
              lambda x, q: fused_quantize_pallas(x, q, bits=4),
              ((rows, C), F32), ((1, 1), F32))
+
+
+@pytest.mark.parametrize("arch,proto_pass,bits", [
+    ("resnet", "exact", 16),
+    ("cnn", "fused", 8),
+])
+def test_round_program_scopes_survive_the_chip_compiler(one_chip,
+                                                        monkeypatch, arch,
+                                                        proto_pass, bits):
+    """Every convolution and dot of the stacked round program, compiled
+    for the chip, carries exactly one ``round.*`` scope: the profiler
+    splits the round's device time by these names."""
+    import round_program as RP
+    built, call = RP.capture_round(monkeypatch, RP.config(arch),
+                                   RP.federation(proto_pass, bits))
+    text = RP.lower(built, call, one_chip).compile().as_text()
+    assert "tpu_custom_call" in text
+    ops = RP.contractions(text)
+    assert ops
+    for op, op_name in ops:
+        assert op_name is not None, op
+        assert len(RP.scopes(op_name)) == 1, op_name
