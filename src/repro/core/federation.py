@@ -104,28 +104,6 @@ def _n_proto_classes(cfg: ModelConfig) -> int:
         else cfg.n_proto_classes
 
 
-def _eval_params(cfg: ModelConfig, params, test_data, batch_size: int = 256):
-    """Global-test macro-F1 with the classifier head."""
-    preds, trues = [], []
-    n = len(next(iter(test_data.values())))
-    for i in range(0, n, batch_size):
-        batch = {k: jnp.asarray(v[i:i + batch_size])
-                 for k, v in test_data.items()}
-        out = forward(cfg, params, batch, remat=False)
-        logits = out.logits
-        if logits.ndim == 3:     # LM: next-token accuracy proxy
-            preds.append(np.asarray(jnp.argmax(logits, -1)).reshape(-1))
-            trues.append(np.asarray(batch["labels"]).reshape(-1))
-        else:
-            preds.append(np.asarray(jnp.argmax(logits, -1)))
-            trues.append(np.asarray(batch["label"]))
-    y_pred = np.concatenate(preds)
-    y_true = np.concatenate(trues)
-    ncls = _n_proto_classes(cfg) if cfg.family in ("cnn", "resnet") \
-        else int(min(cfg.vocab_size, 4096))
-    return macro_f1(y_true, y_pred, ncls), accuracy(y_true, y_pred)
-
-
 # ---------------------------------------------------------------------------
 # per-algorithm wiring (shared by the stacked and the loop engine)
 # ---------------------------------------------------------------------------
@@ -347,10 +325,6 @@ def _stack_round_batches(node_data, batch_size: int, seeds, epochs: int
 
 def _stack_states(states: List[NodeState]) -> NodeState:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
-
-
-def _node_slice(tree, i: int):
-    return jax.tree_util.tree_map(lambda x: x[i], tree)
 
 
 def _masked_select(v, new_tree, old_tree):
@@ -674,64 +648,80 @@ def _make_phase_fns(step: Callable, proto_cfg: ModelConfig, ncls: int, *,
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _batched_eval_fn(cfg: ModelConfig):
-    """One jitted program evaluating EVERY node's student on one test
-    batch: vmap(forward) over the stacked ``[N, ...]`` params, argmax
-    inside the program so only ``[N, B]`` predictions leave the device.
-    Cached by config — traced once per run, not once per node×round."""
+def _eval_fn(cfg: ModelConfig):
+    """The per-round evaluation as one jitted program per test-batch
+    shape.  It takes the student as the round engine holds it (a
+    :class:`Plane` or a pytree), the node to evaluate and one test
+    batch, and returns only the argmax predictions.  ``node`` None: an
+    unstacked student, ``[B]`` predictions; an int: that node of a
+    node-stacked student, ``[B]``; an int vector ``[K]``: those nodes
+    through one vmapped forward, ``[K, B]``.  A language model's
+    predictions carry its ``T`` axis after ``B``.  Cached by config, so
+    the rounds after the first re-use the compiled program."""
 
-    def run(students, batch):
-        out = jax.vmap(lambda p: forward(cfg, p, batch, remat=False))(
-            students)
+    def predict(params, batch):
+        out = forward(cfg, as_tree(params), batch, remat=False)
         return jnp.argmax(out.logits, -1)
+
+    def run(students, node, batch):
+        if node is None:
+            return predict(students, batch)
+        students = jax.tree_util.tree_map(lambda x: x[node], students)
+        if node.ndim:
+            return jax.vmap(predict, in_axes=(0, None))(students, batch)
+        return predict(students, batch)
 
     return jax.jit(run)
 
 
-def _eval_params_batched(cfg: ModelConfig, stacked_students, test_data,
-                         batch_size: int = 256):
-    """All-node global-test metrics from stacked params: one vmapped
-    forward per test batch instead of ``n_nodes`` separate dispatches
-    (the stacked engine's fast path for ``eval_all_nodes``)."""
-    fn = _batched_eval_fn(cfg)
-    tkey = "label" if cfg.family in ("cnn", "resnet") else "labels"
-    preds, trues = [], []
+def _eval_params(cfg: ModelConfig, students, test_data, node=None,
+                 batch_size: int = 256):
+    """Global-test (macro-F1, accuracy) with the classifier head (a
+    language model: next-token accuracy), from :func:`_eval_fn`'s
+    program: one call per test batch, every call dispatched before any
+    prediction is read.  ``node`` as there; an int vector gives one
+    (macro-F1, accuracy) pair per node, in a list."""
+    fn = _eval_fn(cfg)
+    if node is not None:
+        node = np.asarray(node, np.int32)
     n = len(next(iter(test_data.values())))
-    for i in range(0, n, batch_size):
-        batch = {k: jnp.asarray(v[i:i + batch_size])
-                 for k, v in test_data.items()}
-        p = np.asarray(fn(stacked_students, batch))    # [N, B] / [N, B, T]
-        preds.append(p.reshape(p.shape[0], -1))
-        trues.append(np.asarray(batch[tkey]).reshape(-1))
-    y_pred = np.concatenate(preds, axis=1)             # [N, total]
-    y_true = np.concatenate(trues)
-    ncls = _n_proto_classes(cfg) if cfg.family in ("cnn", "resnet") \
-        else int(min(cfg.vocab_size, 4096))
-    return [(macro_f1(y_true, y_pred[i], ncls), accuracy(y_true, y_pred[i]))
-            for i in range(y_pred.shape[0])]
+    preds = [fn(students, node, {k: v[i:i + batch_size]
+                                 for k, v in test_data.items()})
+             for i in range(0, n, batch_size)]
+    spans.count("fed.eval.programs", len(preds))
+    vector = np.ndim(node) == 1
+    rows = len(node) if vector else 1
+    y_pred = np.concatenate([np.asarray(p).reshape(rows, -1)
+                             for p in preds], axis=1)
+    cnn = cfg.family in ("cnn", "resnet")
+    y_true = np.asarray(test_data["label" if cnn else "labels"]).reshape(-1)
+    ncls = _n_proto_classes(cfg) if cnn else int(min(cfg.vocab_size, 4096))
+    scores = [(macro_f1(y_true, p, ncls), accuracy(y_true, p))
+              for p in y_pred]
+    return scores if vector else scores[0]
 
 
-def _eval_nodes(eval_cfg, students_of, n_nodes: int, test_data,
-                eval_all_nodes: bool, extras: Dict[str, Any],
-                *, stacked_students=None):
-    """Per-round evaluation.  Default: node 0 (cheap; exact on full
-    graphs where every node ends identical).  ``eval_all_nodes``
-    evaluates every node and returns the mean — the per-node curves and
-    spread land in extras, so sparse-topology divergence is visible
-    (Fig. 2 as mean±spread over nodes).  When the caller holds stacked
-    ``[N, ...]`` students it passes them as ``stacked_students`` and the
-    per-node loop collapses into one vmapped program per test batch
-    (same metrics, asserted equivalent in tests)."""
+def _eval_nodes(eval_cfg, students, n_nodes: int, test_data,
+                eval_all_nodes: bool, extras: Dict[str, Any]):
+    """Per-round evaluation of ``students``: the stacked engine's
+    node-stacked student, or the loop engine's list of per-node
+    students.  Default: node 0 (cheap; exact on full graphs where every
+    node ends identical).  ``eval_all_nodes`` evaluates every node and
+    returns the mean — the per-node curves and spread land in extras,
+    so sparse-topology divergence is visible (Fig. 2 as mean±spread over
+    nodes); a stacked student takes one vmapped program per test batch
+    for it."""
+    per_node = isinstance(students, list)
     if not eval_all_nodes:
-        return _eval_params(eval_cfg, students_of(0), test_data)
-    if stacked_students is not None:
-        per_node = _eval_params_batched(eval_cfg, stacked_students,
-                                        test_data)
+        return _eval_params(eval_cfg, students[0], test_data) if per_node \
+            else _eval_params(eval_cfg, students, test_data, node=0)
+    if per_node:
+        scores = [_eval_params(eval_cfg, s, test_data) for s in students]
     else:
-        per_node = [_eval_params(eval_cfg, students_of(i), test_data)
-                    for i in range(n_nodes)]
-    f1s = [p[0] for p in per_node]
-    accs = [p[1] for p in per_node]
+        scores = _eval_params(eval_cfg, students, test_data,
+                              node=np.arange(n_nodes))
+    f1s = [p[0] for p in scores]
+    accs = [p[1] for p in scores]
     extras.setdefault("f1_per_round_nodes", []).append(f1s)
     extras.setdefault("acc_per_round_nodes", []).append(accs)
     extras.setdefault("f1_std_per_round", []).append(float(np.std(f1s)))
@@ -982,12 +972,8 @@ def _run_federation(teacher_cfg: ModelConfig, fed: FederationConfig,
         with spans.span("fed.meter"):
             meter.record_round(payload, kind=algo, round_idx=rnd, bits=bits)
         with spans.span("fed.eval"):
-            students = as_tree(stacked.student)
-            f1, acc = _eval_nodes(eval_cfg,
-                                  lambda i: _node_slice(students, i),
-                                  n_nodes, test_data, eval_all_nodes,
-                                  result.extras,
-                                  stacked_students=students)
+            f1, acc = _eval_nodes(eval_cfg, stacked.student, n_nodes,
+                                  test_data, eval_all_nodes, result.extras)
         result.f1_per_round.append(f1)
         result.acc_per_round.append(acc)
         with spans.span("fed.sync"):
@@ -1482,7 +1468,7 @@ def run_federation_loop(teacher_cfg: ModelConfig, fed: FederationConfig,
 
         # 5) evaluation (node 0 by default — exact on full topologies
         #    where all nodes share the model; eval_all_nodes for spread)
-        f1, acc = _eval_nodes(eval_cfg, lambda i: as_tree(states[i].student),
+        f1, acc = _eval_nodes(eval_cfg, [st.student for st in states],
                               n_nodes, test_data, eval_all_nodes,
                               result.extras)
         result.f1_per_round.append(f1)

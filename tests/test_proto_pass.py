@@ -184,28 +184,27 @@ def test_invalid_proto_pass_rejected(mnist_like):
 # ---------------------------------------------------------------------------
 
 def test_batched_eval_matches_per_node_loop(mnist_like):
-    """The one-vmapped-program eval == the per-node ``_eval_params``
-    loop: same per-node (f1, acc) to numerical noise, and ``_eval_nodes``
-    routes through it without changing the recorded extras shape."""
+    """The eval program over every node of a stacked student (one
+    vmapped forward) == the program per unstacked node: same per-node
+    (f1, acc) to numerical noise, and ``_eval_nodes`` routes through it
+    without changing the recorded extras shape."""
     cfg, node_data, test_d = mnist_like
     student = derive_student(cfg)
     stacked = _stacked_students(student, N_NODES)
+    per_node = [jax.tree_util.tree_map(lambda x: x[i], stacked)
+                for i in range(N_NODES)]
 
-    got = F._eval_params_batched(student, stacked, test_d)
-    want = [F._eval_params(student,
-                           jax.tree_util.tree_map(lambda x: x[i], stacked),
-                           test_d)
-            for i in range(N_NODES)]
+    got = F._eval_params(student, stacked, test_d, node=np.arange(N_NODES))
+    want = [F._eval_params(student, p, test_d) for p in per_node]
     for (gf, ga), (wf, wa) in zip(got, want):
         assert abs(gf - wf) < 0.02
         assert abs(ga - wa) < 0.02
 
     extras_b, extras_l = {}, {}
-    f1_b, acc_b = F._eval_nodes(student, None, N_NODES, test_d, True,
-                                extras_b, stacked_students=stacked)
-    f1_l, acc_l = F._eval_nodes(
-        student, lambda i: jax.tree_util.tree_map(lambda x: x[i], stacked),
-        N_NODES, test_d, True, extras_l)
+    f1_b, acc_b = F._eval_nodes(student, stacked, N_NODES, test_d, True,
+                                extras_b)
+    f1_l, acc_l = F._eval_nodes(student, per_node, N_NODES, test_d, True,
+                                extras_l)
     assert abs(f1_b - f1_l) < 0.02 and abs(acc_b - acc_l) < 0.02
     assert len(extras_b["f1_per_round_nodes"][0]) == N_NODES
     np.testing.assert_allclose(extras_b["f1_per_round_nodes"],
