@@ -43,12 +43,11 @@ def main(argv=None) -> int:
         return 2
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    config, traffic, workload = cell["config"], cell["traffic"], \
-        cell["workload"]
+    workload = cell["workload"]
     kw = dict(block_nodes=workload["reference_block_nodes"])
     for seed in args.seeds:
-        data, _ = stacked.make_federation_data(seed, config, traffic)
-        fed = stacked.reference_federation(config, traffic, seed)
+        data, _ = stacked.make_federation_data(seed, cell)
+        fed = stacked.reference_federation(cell, seed)
         want = stacked.reference_readings(fed, data, stacked.COMPARED_ROUNDS,
                                           **kw)
         for variant in args.variants:

@@ -44,24 +44,14 @@ def forward_flops(blocks, width: int, hw, proto_dim: int,
     return total
 
 
-def _fwd(config: dict, part: str) -> int:
+def sample_flops(config: dict, part: str) -> int:
+    """FLOPs of one image's forward pass through ``part`` (``"teacher"``
+    or ``"student"``): the family's count that ``mfu.round`` composes
+    into a round."""
     p = config[part]
     return forward_flops(p["resnet_blocks"], p["resnet_width"],
                          config["input_hw"], config["proto_dim"],
                          config["num_classes"])
-
-
-def round_flops(config: dict, teacher_on: bool, eval_images: int) -> int:
-    """Model FLOPs of one ProFe round over all nodes: student training,
-    teacher training when it is on, the exact Eq. 3 pass (one student
-    forward per local image) and the evaluation of one node's student on
-    the test images."""
-    images = config["nodes"] * config["images_per_node"]
-    student = _fwd(config, "student")
-    total = images * (3 * student + student)
-    if teacher_on:
-        total += images * 3 * _fwd(config, "teacher")
-    return total + eval_images * student
 
 
 def param_sizes(config: dict, part: str) -> list:

@@ -37,7 +37,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from bench import compare
+from bench import compare, harness
 from bench import reference as ref
 from bench.generate import make_federation_data
 from bench.harness import WINDOW_LABEL
@@ -216,15 +216,16 @@ def _hyper(config: dict, traffic: dict) -> ref.Hyper:
                      grad_clip=t["grad_clip"])
 
 
-def reference_federation(config: dict, traffic: dict, seed: int
-                         ) -> ref.Federation:
+def reference_federation(cell: dict, seed: int) -> ref.Federation:
+    config, traffic = cell["config"], cell["traffic"]
+
     def arch(part):
         return ref.Arch(tuple(config[part]["resnet_blocks"]),
                         config[part]["resnet_width"], config["input_hw"][2],
                         config["proto_dim"], config["num_classes"])
     if traffic["topology"] != "full":
         raise ValueError(f"unsupported topology {traffic['topology']!r}")
-    n = config["nodes"]
+    n = harness.nodes(cell)
     return ref.Federation(
         teacher=arch("teacher"), student=arch("student"),
         hyper=_hyper(config, traffic), bits=traffic["quantize_bits"],
@@ -319,11 +320,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         cell["workload"]
     cfg = model_configs(config)
     t_data = time.time()
-    node_data, test = make_federation_data(seed, config, traffic)
+    node_data, test = make_federation_data(seed, cell)
     log(f"data {time.time() - t_data:.2f} s")
     t = config["train"]
     fed = FederationConfig(
-        num_nodes=config["nodes"], topology=traffic["topology"],
+        num_nodes=harness.nodes(cell), topology=traffic["topology"],
         rounds=MAX_ROUNDS, local_epochs=traffic["local_epochs"],
         algorithm="profe", kd_temperature=config["profe"]["kd_temperature"],
         alpha_s=traffic["alpha_s"], alpha_limit=traffic["alpha_limit"],
@@ -371,7 +372,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     gc.collect()                 # the program's state, before the reference
     t_ref = time.time()
     want = reference_readings(
-        reference_federation(config, traffic, seed), node_data,
+        reference_federation(cell, seed), node_data,
         COMPARED_ROUNDS, block_nodes=workload["reference_block_nodes"])
     log(f"reference {time.time() - t_ref:.1f} s")
     values = readings(prog, want)

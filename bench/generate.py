@@ -12,6 +12,8 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from bench import harness
+
 
 def _templates(rng, classes: int, hw) -> np.ndarray:
     h, w, c = hw
@@ -27,17 +29,18 @@ def _templates(rng, classes: int, hw) -> np.ndarray:
     return out
 
 
-def make_federation_data(seed: int, config: dict, traffic: dict
+def make_federation_data(seed: int, cell: dict
                          ) -> Tuple[List[Dict[str, np.ndarray]],
                                     Dict[str, np.ndarray]]:
-    """-> (per-node training shards, test set), each
-    ``{"image": [n, H, W, C] float32, "label": [n] int32}``."""
+    """-> (per-node training shards over ``harness.nodes``, test set),
+    each ``{"image": [n, H, W, C] float32, "label": [n] int32}``."""
+    config, traffic = cell["config"], cell["traffic"]
     if traffic.get("split", "iid") != "iid":
         raise ValueError(f"unsupported split {traffic['split']!r}")
     rng = np.random.default_rng(seed)
     hw = tuple(config["input_hw"])
     classes = config["num_classes"]
-    nodes = config["nodes"]
+    nodes = harness.nodes(cell)
     per_node = config["images_per_node"]
     n_test = config["test_images"]
     n = nodes * per_node + n_test

@@ -5,8 +5,14 @@ Everything that belongs to one cell, configuration, traffic mix, entry
 or per-layer metric sits in a file of its own, found by the names in
 ``BENCHMARK.json``:
 
-* ``bench/configs/<config>.json``  — the model configuration as run,
-* ``bench/traffic/<traffic>.json`` — the federation job's parameters,
+* ``bench/configs/<config>.json``  — the model configuration as run;
+  its ``family`` names its FLOP count,
+* ``bench/counts/<family>.py``     — ``sample_flops(config, part)``,
+  the model FLOPs of one sample's forward through the teacher or the
+  student,
+* ``bench/traffic/<traffic>.json`` — the federation job's parameters;
+  it may set ``nodes`` (read through ``nodes``), the configuration
+  gives the rest,
 * ``bench/workloads/<cell>.json``  — the entry kind, the reference's
   block size and the limits of the comparison with the reference,
 * ``bench/entries/<kind>.py``      — ``run(cell, seed, seconds, trace,
@@ -54,6 +60,22 @@ def applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def configs(family: Optional[str] = None, repo: Path = REPO) -> List[str]:
+    """Names of the configurations ``BENCHMARK.json`` lists, of
+    ``family`` only where it is given."""
+    return [c["name"] for c in manifest(repo)["configs"]
+            if family is None
+            or load_json(repo / c["file"]).get("family") == family]
+
+
+def nodes(cell: dict) -> int:
+    """The cell's number of federation nodes: the traffic's ``nodes``
+    where it sets it, else the configuration's.  A traffic that sets
+    another number than the configuration's cuts the deployment, and
+    names the cut in its own ``reduced`` and ``published``."""
+    return cell["traffic"].get("nodes", cell["config"]["nodes"])
+
+
 def load_cell(name: str, repo: Path = REPO) -> dict:
     """Everything one cell needs, from the files ``BENCHMARK.json`` names."""
     man = manifest(repo)
@@ -93,13 +115,24 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
-def read_metric(name: str, ctx, bench: Path = BENCH) -> Optional[float]:
-    path = bench / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+def load_module(path: Path, name: str):
+    """The Python file at ``path`` as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
+
+
+def family_count(family: str, bench: Path = BENCH):
+    """The FLOP count of a configuration family,
+    ``bench/counts/<family>.py``."""
+    return load_module(bench / "counts" / f"{family}.py",
+                       f"bench_count_{family.replace('-', '_')}")
+
+
+def read_metric(name: str, ctx, bench: Path = BENCH) -> Optional[float]:
+    return load_module(bench / "metrics" / f"{name}.py",
+                       f"bench_metric_{name.replace('.', '_')}").read(ctx)
 
 
 def per_layer(cell: dict, out: dict, device: dict, trace_dir: Path):

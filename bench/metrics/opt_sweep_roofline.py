@@ -8,6 +8,4 @@ from bench.roofline import hbm_share
 
 
 def read(ctx):
-    return hbm_share(ctx, "round.student",
-                     opt_sweep_round_bytes(ctx.cell["config"],
-                                           ctx.cell["traffic"]))
+    return hbm_share(ctx, "round.student", opt_sweep_round_bytes(ctx.cell))

@@ -49,9 +49,8 @@ def test_planted_fault_is_not_correct(name, fault, monkeypatch):
 def test_control_in_lower_precision_fails_the_limits(name):
     cell = _tiny(name)
     seed = 2 ** 31 + 43
-    data, _ = stacked.make_federation_data(seed, cell["config"],
-                                           cell["traffic"])
-    fed = stacked.reference_federation(cell["config"], cell["traffic"], seed)
+    data, _ = stacked.make_federation_data(seed, cell)
+    fed = stacked.reference_federation(cell, seed)
     want = stacked.reference_readings(fed, data, 2, block_nodes=2)
     ctrl = stacked.reference_readings(fed, data, 2, block_nodes=2,
                                       rnd=ref.float8_round())
@@ -116,10 +115,10 @@ def test_student_traffic_leaves_the_reference_teacher_untouched():
     traffic, and its local round with the teacher off returns a given
     teacher and its moments bit for bit."""
     cell = _tiny("c10r18.student")
-    config, traffic = cell["config"], cell["traffic"]
+    config = cell["config"]
     seed = 2 ** 31 + 29
-    data, _ = stacked.make_federation_data(seed, config, traffic)
-    fed = stacked.reference_federation(config, traffic, seed)
+    data, _ = stacked.make_federation_data(seed, cell)
+    fed = stacked.reference_federation(cell, seed)
     seen = []
     ref.run_rounds(fed, data, 3, block_nodes=2,
                    on_round=lambda r, st, loss, gp: seen.append(
@@ -128,7 +127,7 @@ def test_student_traffic_leaves_the_reference_teacher_untouched():
                             st["opt_t"]["nu"]))))
     assert len(seen) == 4 and not any(seen)
 
-    n, b = config["nodes"], config["batch_size"]
+    n, b = harness.nodes(cell), config["batch_size"]
     st = ref.init_nodes(fed, n, teacher=True)
     steps = config["images_per_node"] // b
     imgs = np.stack([d["image"][:steps * b].reshape(steps, b,

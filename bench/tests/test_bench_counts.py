@@ -8,7 +8,7 @@ from bench import harness
 from bench import reference as ref
 from bench.counts import resnet
 
-CONFIGS = [c["name"] for c in harness.manifest()["configs"]]
+CONFIGS = harness.configs("resnet")
 
 
 def _config(name):

@@ -70,6 +70,45 @@ def test_every_config_has_a_cell_and_every_cell_its_files():
         assert cell["per_layer"]
 
 
+@pytest.mark.parametrize("name", harness.configs())
+def test_family_names_a_count(name):
+    """Each configuration names its family, whose count
+    ``bench/counts/<family>.py`` gives the FLOPs of one sample's forward
+    through the teacher and the student, from which ``mfu.round``
+    composes a round."""
+    entry = next(c for c in MAN["configs"] if c["name"] == name)
+    config = harness.load_json(harness.REPO / entry["file"])
+    family = config["family"]
+    assert NAME.match(family), family
+    assert (harness.BENCH / "counts" / f"{family}.py").is_file()
+    count = harness.family_count(family)
+    for part in ("teacher", "student"):
+        flops = count.sample_flops(config, part)
+        assert isinstance(flops, int) and flops > 0, (part, flops)
+
+
+def undeclared_cuts(cell) -> list:
+    """The keys a cell's traffic sets at another value than its
+    configuration's without naming the cut: such a key has to be in the
+    traffic's ``reduced``, with the deployment's published value (the
+    configuration's ``published`` one, else its own) in the traffic's
+    ``published``."""
+    config, traffic = cell["config"], cell["traffic"]
+    published = dict(config, **config.get("published", {}))
+    named, given = traffic.get("reduced", []), traffic.get("published", {})
+    return sorted(k for k in set(traffic) & set(config) - {"reduced",
+                                                          "published"}
+                  if traffic[k] != config[k]
+                  and (k not in named or given.get(k) != published[k]))
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in MAN["workloads"]])
+def test_traffic_names_its_cuts(name):
+    """A traffic that overrides a configuration key (``nodes``, which
+    ``harness.nodes`` reads) cuts the deployment, and says so."""
+    assert not undeclared_cuts(harness.load_cell(name))
+
+
 def test_four_chip_cells_are_few():
     four = [w for w in MAN["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(MAN["workloads"]) // 2)

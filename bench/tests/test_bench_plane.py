@@ -16,13 +16,15 @@ from bench.entries import stacked
 from repro.models import derive_student, init_params
 from repro.optim.plane import Plane, make_plane_optimizer, plane_from_tree
 
-CONFIGS = [c["name"] for c in harness.manifest()["configs"]]
+CONFIGS = harness.configs("resnet")
 NODES = 2
 
 
-def _config(name):
+def _cell(name):
     cfg = harness.load_json(harness.BENCH / "configs" / f"{name}.json")
-    return dict(cfg, nodes=NODES)
+    return {"config": dict(cfg, nodes=NODES),
+            "traffic": harness.load_json(harness.BENCH / "traffic"
+                                         / "kd.json")}
 
 
 def _pallas_bytes(jaxpr):
@@ -63,7 +65,8 @@ def test_opt_sweep_bytes_equal_the_kernel_operands(name):
     """One step's clip+AdamW update over every node's plane, traced as
     the round vmaps it: one ``pallas_call`` whose plane operands and
     results are the count; its scalars are a few bytes a node."""
-    cfg = _config(name)
+    cell = _cell(name)
+    cfg = cell["config"]
     plane = _student_plane(cfg)
     assert plane.buf.shape == (NODES, counts.plane_rows(cfg), counts.COLS)
     t = cfg["train"]
@@ -77,7 +80,7 @@ def test_opt_sweep_bytes_equal_the_kernel_operands(name):
     big = [b for shape, b in calls[0] if shape == plane.buf.shape]
     rest = [b for shape, b in calls[0] if shape != plane.buf.shape]
     assert len(big) == 7
-    assert sum(big) == counts.opt_sweep_bytes(cfg)
+    assert sum(big) == counts.opt_sweep_bytes(cell)
     assert sum(rest) <= 16 * NODES
 
 
@@ -86,10 +89,8 @@ def _op(start, end, name):
 
 
 def _ctx(window=(0.0, 1e6)):
-    cfg = _config("cifar100-resnet32")
-    traffic = harness.load_json(harness.BENCH / "traffic" / "kd.json")
     return SimpleNamespace(
-        cell={"config": cfg, "traffic": traffic}, out={"traced_rounds": 2},
+        cell=_cell("cifar100-resnet32"), out={"traced_rounds": 2},
         trace=T.Trace({}, [], window),
         peaks={"hbm_bytes_per_s": 1e12})
 
@@ -105,10 +106,9 @@ def test_roofline_counts_the_scope_pallas_calls_inside_the_window(
            _op(2e6, 2e6 + 100.0, sweep)]            # outside the window
     monkeypatch.setattr(S, "load_ops", lambda _d: {"/device:TPU:0": ops})
     ctx = _ctx()
-    cfg, traffic = ctx.cell["config"], ctx.cell["traffic"]
     # two traced rounds' bytes over 200 ns and 40 ns at 1e12 bytes/s
     assert harness.read_metric("opt_sweep_roofline", ctx) == pytest.approx(
-        100.0 * 2 * counts.opt_sweep_round_bytes(cfg, traffic)
+        100.0 * 2 * counts.opt_sweep_round_bytes(ctx.cell)
         / (200e-9 * 1e12))
     assert roofline.hbm_share(ctx, "round.mix", 1) is None
 

@@ -18,15 +18,16 @@ def _leaves(tree):
     return {jax.tree_util.keystr(p): np.asarray(x) for p, x in flat}
 
 
-@pytest.mark.parametrize("name", [c["name"] for c in
-                                  harness.manifest()["configs"]])
+@pytest.mark.parametrize("name", harness.configs("resnet"))
 def test_initial_weights_equal_the_program(name):
     from repro.models import init_params
     cfg_file = harness.load_json(harness.BENCH / "configs" / f"{name}.json")
     teacher = stacked.model_configs(cfg_file)
     from repro.models import derive_student
     fed = stacked.reference_federation(
-        cfg_file, harness.load_json(harness.BENCH / "traffic" / "kd.json"), 5)
+        {"config": cfg_file,
+         "traffic": harness.load_json(harness.BENCH / "traffic" / "kd.json")},
+        5)
     k1, k2 = jax.random.split(jax.random.PRNGKey(fed.seed * 1000 + 3))
     for cfg, arch, key in ((teacher, fed.teacher, k1),
                            (derive_student(teacher), fed.student, k2)):
@@ -53,9 +54,8 @@ def test_control_in_lower_precision_fails_the_limits():
     limits."""
     cell = tiny_cell()
     seed = 2 ** 31 + 77
-    data, _ = stacked.make_federation_data(seed, cell["config"],
-                                           cell["traffic"])
-    fed = stacked.reference_federation(cell["config"], cell["traffic"], seed)
+    data, _ = stacked.make_federation_data(seed, cell)
+    fed = stacked.reference_federation(cell, seed)
     want = stacked.reference_readings(fed, data, 3, block_nodes=2)
     ctrl = stacked.reference_readings(fed, data, 3, block_nodes=2,
                                       rnd=ref.float8_round())
